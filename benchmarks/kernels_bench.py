@@ -59,10 +59,7 @@ def _outer_step_bench(out: Dict[str, float], smoke: bool) -> None:
             return ref.outer_step_ref(d_, e_, q_)[:3]
 
         refjit = jax.jit(lambda d_, e_, q_: ref.outer_step_ref(d_, e_, q_))
-        # row_cap covers the matrix in one tile: on the CPU interpret lane
-        # the binding constraint is per-grid-step overhead, not VMEM
-        fused = jax.jit(lambda d_, e_, q_: fused_compress_ef(
-            d_, e_, q_, row_cap=8192))
+        fused = jax.jit(lambda d_, e_, q_: fused_compress_ef(d_, e_, q_))
 
         tag = f"{m}x{n}_r{r}"
         t_unf = _time(unfused, d, e, q, iters=iters)

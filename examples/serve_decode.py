@@ -6,22 +6,16 @@ footprint is compared against the dense per-slot-max-length layout.
   PYTHONPATH=src python examples/serve_decode.py --dense   # legacy driver
 
 Unsupported families (SSM/MLA/enc-dec) fall back to the dense driver
-subprocess, same as ``repro.launch.serve`` without ``--paged``.
+(``repro.launch.serve`` without ``--paged``), in this process.  Both run
+on the first device jax sees: the CPU, or one TPU chip.
 """
 import argparse
-import os
-import subprocess
-import sys
 
 
-def _dense_fallback(arch: str) -> int:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    cmd = [sys.executable, "-m", "repro.launch.serve",
-           "--arch", arch, "--smoke", "--devices", "4",
-           "--batch", "4", "--prompt-len", "12", "--gen-len", "12"]
-    print(" ".join(cmd))
-    return subprocess.run(cmd, env=env).returncode
+def _dense_fallback(arch: str) -> None:
+    from repro.launch import serve
+    serve.main(["--arch", arch, "--smoke", "--batch", "4",
+                "--prompt-len", "12", "--gen-len", "12"])
 
 
 def main() -> None:
@@ -32,7 +26,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.dense:
-        sys.exit(_dense_fallback(args.arch))
+        _dense_fallback(args.arch)
+        return
 
     import jax
     import numpy as np
@@ -45,7 +40,8 @@ def main() -> None:
     ok, why = supports_paged(cfg)
     if not ok:
         print(f"{args.arch}: {why} -> dense driver")
-        sys.exit(_dense_fallback(args.arch))
+        _dense_fallback(args.arch)
+        return
 
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     ps, max_pages, gen = 4, 4, 6

@@ -178,8 +178,8 @@ class LowRankQuant(Compressor):
     min_dim_for_lowrank: int = 64  # small tensors skip the low-rank stage
     name: str = "diloco_x"
     # "ref": the unfused jnp op-chain below.  "pallas": the fused
-    # compress+EF kernel pipeline (kernels/fused_compress.py, interpret
-    # mode on CPU) — same wire format bit-for-bit, reconstruction within a
+    # compress+EF kernel pipeline (kernels/fused_compress.py) — same wire
+    # format bit-for-bit, reconstruction within a
     # documented reorder-ulp bound of the ref chain, identical adaptive-
     # rank masking contract.  Threaded through ``per_cluster_compress``
     # unchanged (the backend only changes what ``roundtrip`` dispatches
@@ -201,7 +201,10 @@ class LowRankQuant(Compressor):
             if min(m, n) < self.min_dim_for_lowrank:
                 return jnp.zeros((0,), jnp.float32)
             r = min(self.rank, m, n)
-            key = jax.random.PRNGKey(zlib.crc32(str(x.shape).encode()) % (2 ** 31))
+            # keyed by the matrix PowerSGD sees, so a stacked leaf draws the
+            # same Q however its leading dims split (pipeline stages x layers)
+            key = jax.random.PRNGKey(zlib.crc32(str((m, n)).encode())
+                                     % (2 ** 31))
             return jax.random.normal(key, (n, r), jnp.float32)
         return jax.tree.map(mk, params)
 

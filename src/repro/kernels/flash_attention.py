@@ -6,7 +6,11 @@ k/v blocks, maintaining running max/denominator in f32 (Rabe-Staats /
 FlashAttention recurrence). GQA is handled by the wrapper (kv heads are
 index-mapped, not materialized, via the BlockSpec head mapping).
 
-Validated in interpret mode against ``ref.flash_attention_ref``.
+Forward only: there is no backward kernel, so differentiating through it
+raises (``_no_backward``) rather than handing Mosaic a gradient it cannot
+compile.  Training takes the XLA attention path (``REPRO_USE_PALLAS``
+unset).  Validated against ``ref.flash_attention_ref`` in interpret mode
+and on the chip.
 """
 from __future__ import annotations
 
@@ -17,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import kernels
 
 NEG_INF = -1e30
 
@@ -38,7 +44,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q = q_ref[0].astype(jnp.float32)                 # (bq, d)
     k = k_ref[0].astype(jnp.float32)                 # (bk, d)
     v = v_ref[0].astype(jnp.float32)                 # (bk, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                precision=kernels.F32) * scale
 
     if causal:
         q_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -53,7 +60,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     alpha = jnp.exp(m_prev - m_new)
     l_new = l_prev * alpha + p.sum(axis=1)
     acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jnp.dot(p, v, preferred_element_type=jnp.float32))
+                    + jnp.dot(p, v, preferred_element_type=jnp.float32,
+                              precision=kernels.F32))
     m_ref[...] = m_new
     l_ref[...] = l_new
 
@@ -66,9 +74,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            *, causal: bool = True, bq: int = 256,
-                           bk: int = 256, interpret: bool = True
-                           ) -> jnp.ndarray:
+                           bk: int = 256) -> jnp.ndarray:
     """q: (B,Sq,H,d); k,v: (B,Sk,KV,d). Returns (B,Sq,H,d)."""
+    return _flash(q, k, v, causal, bq, bk)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, bq, bk):
     B, Sq, H, d = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -109,6 +121,14 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq_,), jnp.float32),     # running denom
             pltpu.VMEM((bq_, d), jnp.float32),   # accumulator
         ],
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(qh, kh, vh)
     return out.reshape(B, H, Sq, d).transpose(0, 2, 1, 3)
+
+
+@_flash.defjvp
+def _no_backward(causal, bq, bk, primals, tangents):
+    raise NotImplementedError(
+        "flash_attention_pallas is forward-only (no backward kernel): "
+        "differentiate through the XLA attention path instead, i.e. leave "
+        "REPRO_USE_PALLAS unset for training")

@@ -3,11 +3,12 @@
 The per-round compressor is the outer step's compute hot path: per
 parameter matrix it runs  EF add -> PowerSGD project -> Cholesky-QR ->
 back-project -> int4 quantize -> pack -> reconstruct -> EF residual.  As
-separate XLA ops every arrow materializes an HBM-sized intermediate (the
-EF-corrected delta alone is touched five times).  This module fuses the
-chain into three Pallas kernels plus one tiny host-level r x r step:
+separate XLA ops every arrow over the (m, n) matrix materializes an
+HBM-sized intermediate (the EF-corrected delta alone is touched five
+times).  This module keeps every (m, n) pass inside a Pallas kernel and
+leaves only the skinny (dim, r) factor work between them:
 
-  1. ``_proj_kernel``        P = (delta + e) @ (Q_warm * mask)
+  1. ``_proj_kernel``    P = (delta + e) @ (Q_warm * mask)
         The EF add happens on the operand tile in VMEM — the (m, n)
         corrected delta is never materialized.  Tiled matmul with f32
         VMEM accumulation (the ``lowrank_mm`` pattern).
@@ -16,19 +17,14 @@ chain into three Pallas kernels plus one tiny host-level r x r step:
         Kept as jnp ops between the kernels (``core.compression``'s
         ``_orthonormalize`` is the single implementation; its relative-eps
         ridge lesson applies verbatim).
-  3. ``_proj_t_pack_kernel`` Q = (delta + e)^T @ P, and on the final K
-        step the flush quantizes the finished (bn, r) tile block-wise and
-        packs two int4 codes per byte *in the same kernel* — the wire
-        payload leaves the pallas_call; no separate quantize pass over Q.
-        (P is packed by ``quant4.quant4_pack_pallas`` after the host
-        orthonormalization step that sits between its projection and its
-        quantization.)
-  4. ``_recon_kernel``       delta_hat = dequant(P) @ dequant(Q)^T and
-        e' = (delta + e) - delta_hat, both written by one grid cell from
-        the *packed* factors — the decompress dual (unpack -> dequant ->
-        P Q^T) fused with the error-feedback residual, so neither the
-        dequantized factors nor the reconstruction round-trips HBM
-        between ops.
+  3. ``_proj_t_kernel``  Q = (delta + e)^T @ P, contracting the row axis
+        of both tiles in ``dot_general`` (no relayout of the (m, n) tile).
+  4. int4 quantize + pack of both factors with ``quant4.quant4_pack_pallas``
+        (the wire payload), and their dequantization with its unpack dual.
+  5. ``_recon_kernel``   delta_hat = P_q @ Q_q^T and e' = (delta + e) -
+        delta_hat, both written by one grid cell — the decompress dual
+        fused with the error-feedback residual, so the reconstruction
+        never round-trips HBM between ops.
 
 Adaptive-rank contract (jit-shape-stable, from ``core.compression``):
 factors are allocated at the warm start's full width ``r_max``; a traced
@@ -38,55 +34,57 @@ quantized codes are zero — wire-byte accounting may bill only r_t columns
 while the arrays (and the compiled program) keep one shape.
 
 Wire format is bit-identical to ``ref.quant4_pack_ref`` on the row-major
-flattened factor: row tiles are chosen so ``tile_rows * r % block == 0``
-(quantization blocks never straddle a tile boundary) and grid padding
-appends zero rows only, which quantize to the same zero codes the
-reference pads with.
+flattened factor: the factors are packed unpadded, exactly as the
+reference packs them.  Grid padding appends zero rows/columns to the
+(m, n) operands only, which add nothing to either projection.
 
-Interpret-vs-TPU caveats: everything here runs under ``interpret=True``
-on CPU (the correctness lane; it is jit-traceable, so the grid loops
-compile).  The transposed projection accumulates Q^T via
-``dot_general`` dimension_numbers (no ``m_tile.T`` relayout) — the
-MXU-native form on TPU and ~1.6x faster on the CPU lane too.  On real
-TPU: the flush-step reshapes used for packing prefer a (2, block/2)
-sublane layout, and 1-D BlockSpecs (scales) should be widened to
-(rows, 1).  The BlockSpec tiling — the part that carries to hardware —
-is MXU-aligned as long as ``row_cap`` stays a multiple of 128; in
-interpret mode each grid step pays a Python-level tile copy, so the
-benchmark lane raises ``row_cap`` to cover the matrix in one tile
-(grid-step overhead, not VMEM, is the binding constraint on CPU).
+Tiling: (bm, bn) tiles of the (m, n) operands, rows a multiple of 16 and
+columns of 128 (the TPU's f32/bf16 tiling), capped at ``row_cap`` and
+halved until the largest kernel's double-buffered blocks fit
+``_VMEM_BUDGET`` — under the 16 MiB scoped-VMEM default Mosaic compiles
+against on v5e.  The factor width r is always one whole block.
 """
 from __future__ import annotations
 
 import functools
-import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+from repro.kernels.quant4 import quant4_pack_pallas, quant4_unpack_pallas
 from repro.kernels.ref import FusedPayload
+
+_VMEM_BUDGET = 12 * 2 ** 20
 
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _row_tile(dim: int, r: int, block: int, cap: int) -> int:
-    """Row-tile size for an (dim, r) factor such that every tile holds a
-    whole number of flat quantization blocks: tile * r % block == 0."""
-    unit = block // math.gcd(r, block)
-    full = _ceil_to(max(dim, 1), unit)
-    if unit >= cap:
-        return full if full <= unit else unit * (cap // unit or 1)
-    return min(full, (cap // unit) * unit)
+def _vmem_bytes(bm: int, bn: int, r: int) -> int:
+    """f32 VMEM of the reconstruct+EF kernel, the largest of the three:
+    double-buffered P/Q factor tiles, two (bm, bn) operands and two
+    outputs, plus the product tile."""
+    return 4 * (2 * (bm * r + bn * r + 4 * bm * bn) + bm * bn)
+
+
+def _tiles(m: int, n: int, r: int, row_cap: int) -> Tuple[int, int]:
+    cap = max(128, _ceil_to(row_cap, 128))
+    while True:
+        bm = min(cap, _ceil_to(m, 16))
+        bn = min(cap, _ceil_to(n, 128))
+        if cap <= 128 or _vmem_bytes(bm, bn, r) <= _VMEM_BUDGET:
+            return bm, bn
+        cap //= 2
 
 
 def _pad2d(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
-    p0, p1 = _ceil_to(x.shape[0], m0) - x.shape[0], \
-        _ceil_to(x.shape[1], m1) - x.shape[1]
+    p0 = _ceil_to(x.shape[0], m0) - x.shape[0]
+    p1 = _ceil_to(x.shape[1], m1) - x.shape[1]
     if p0 or p1:
         x = jnp.pad(x, ((0, p0), (0, p1)))
     return x
@@ -98,19 +96,16 @@ def _col_mask(r: int, rank_scalar) -> jnp.ndarray:
     return (jnp.arange(r) < rank_scalar).astype(jnp.float32)
 
 
-# ---------------------------------------------------------------------------
-# kernel 1: P = (D + E) @ Qm, EF add fused into the operand load
-# ---------------------------------------------------------------------------
+def _operand(d_ref, e_ref):
+    """The EF-corrected (bm, bn) tile, formed in VMEM."""
+    m_tile = d_ref[...].astype(jnp.float32)
+    if e_ref is not None:
+        m_tile = m_tile + e_ref[...]
+    return m_tile
 
-def _proj_kernel(*refs, n_k: int, with_e: bool):
-    if with_e:
-        d_ref, e_ref, q_ref, o_ref, acc_ref = refs
-        m_tile = d_ref[...].astype(jnp.float32) + e_ref[...]
-    else:
-        d_ref, q_ref, o_ref, acc_ref = refs
-        m_tile = d_ref[...].astype(jnp.float32)
+
+def _accumulate(acc_ref, o_ref, prod, n_k: int):
     k = pl.program_id(1)
-    prod = jnp.dot(m_tile, q_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k == 0)
     def _first():                       # no zero-init pass on step 0
@@ -125,208 +120,129 @@ def _proj_kernel(*refs, n_k: int, with_e: bool):
         o_ref[...] = acc_ref[...]
 
 
-def _project(d, e, qm, bm: int, bn: int, interpret: bool) -> jnp.ndarray:
-    """(M_pad, N_pad) x (N_pad, r) -> (M_pad, r) f32; d/e pre-padded."""
-    M, N = d.shape
-    r = qm.shape[1]
-    gm, gk = M // bm, N // bn
-    with_e = e is not None
-    in_specs = [pl.BlockSpec((bm, bn), lambda i, k: (i, k))]
-    ins = [d]
-    if with_e:
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, k: (i, k)))
-        ins.append(e)
-    in_specs.append(pl.BlockSpec((bn, r), lambda i, k: (k, 0)))
-    ins.append(qm)
-    return pl.pallas_call(
-        functools.partial(_proj_kernel, n_k=gk, with_e=with_e),
-        grid=(gm, gk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, r), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, r), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
-        interpret=interpret,
-    )(*ins)
-
-
 # ---------------------------------------------------------------------------
-# kernel 2: Q = (D + E)^T @ P with the int4 quantize+pack fused in the flush
+# kernels 1 and 3: P = (D + E) @ Qm and Q = (D + E)^T @ P
 # ---------------------------------------------------------------------------
 
-def _quant_pack_tile(q: jnp.ndarray, block: int):
-    """(rows, r) f32 -> (packed (nblk, block//2) uint8, scales (nblk,)).
-    Exactly ``ref.quant4_pack_ref`` on the row-major flat tile."""
-    nblk = q.size // block
-    flat = q.reshape(nblk, block)
-    amax = jnp.max(jnp.abs(flat), axis=1)
-    scale = jnp.where(amax == 0.0, 1.0, amax / 7.0)
-    codes = jnp.clip(jnp.round(flat / scale[:, None]), -8, 7).astype(
-        jnp.int32)
-    qu = (codes & 0xF).astype(jnp.uint8)
-    pair = qu.reshape(nblk, block // 2, 2)
-    return pair[:, :, 0] | (pair[:, :, 1] << 4), scale
-
-
-def _proj_t_pack_kernel(*refs, n_k: int, with_e: bool, block: int):
+def _proj_kernel(*refs, n_k: int, with_e: bool):
     if with_e:
-        d_ref, e_ref, p_ref, q_ref, packed_ref, scale_ref, acc_ref = refs
-        m_tile = d_ref[...].astype(jnp.float32) + e_ref[...]
+        d_ref, e_ref, q_ref, o_ref, acc_ref = refs
     else:
-        d_ref, p_ref, q_ref, packed_ref, scale_ref, acc_ref = refs
-        m_tile = d_ref[...].astype(jnp.float32)
-    k = pl.program_id(1)
-    # accumulate Q^T = P^T (D+E): dimension_numbers contract row axes
-    # directly instead of relaying out m_tile.T — ~1.6x faster on the CPU
-    # lane and the MXU-native form on TPU (no transpose unit pass).
+        (d_ref, q_ref, o_ref, acc_ref), e_ref = refs, None
+    prod = jnp.dot(_operand(d_ref, e_ref), q_ref[...],
+                   preferred_element_type=jnp.float32,
+                   precision=kernels.F32)
+    _accumulate(acc_ref, o_ref, prod, n_k)
+
+
+def _proj_t_kernel(*refs, n_k: int, with_e: bool):
+    if with_e:
+        d_ref, e_ref, p_ref, o_ref, acc_ref = refs
+    else:
+        (d_ref, p_ref, o_ref, acc_ref), e_ref = refs, None
     prod = jax.lax.dot_general(
-        p_ref[...], m_tile, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == 0)
-    def _first():                       # no zero-init pass on step 0
-        acc_ref[...] = prod
-
-    @pl.when(k > 0)
-    def _rest():
-        acc_ref[...] += prod
-
-    @pl.when(k == n_k - 1)
-    def _flush():
-        q = acc_ref[...].T                  # (bn, r): row-major factor tile
-        q_ref[...] = q
-        packed, scale = _quant_pack_tile(q, block)
-        packed_ref[...] = packed
-        scale_ref[...] = scale
+        _operand(d_ref, e_ref), p_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=kernels.F32)
+    _accumulate(acc_ref, o_ref, prod, n_k)
 
 
-def _project_t_pack(d, e, p, bm: int, bn: int, block: int, interpret: bool):
-    """Q = (D+E)^T @ P plus fused pack.  Returns (Q (N_pad, r) f32,
-    packed (N_pad*r//block, block//2) uint8, scales (N_pad*r//block,))."""
+def _project(d, e, f, bm: int, bn: int, *, transpose: bool) -> jnp.ndarray:
+    """``transpose=False``: (M, N) x f (N, r) -> (M, r).
+    ``transpose=True``: (M, N)^T x f (M, r) -> (N, r).
+    d/e pre-padded to the tile grid; the grid's inner axis is the
+    contraction."""
     M, N = d.shape
-    r = p.shape[1]
-    gn, gk = N // bn, M // bm
-    nblk_tile = bn * r // block
+    r = f.shape[1]
+    if transpose:
+        grid, out_rows, bo = (N // bn, M // bm), N, bn
+        tile = pl.BlockSpec((bm, bn), lambda i, k: (k, i))
+        f_spec = pl.BlockSpec((bm, r), lambda i, k: (k, 0))
+        kernel = _proj_t_kernel
+    else:
+        grid, out_rows, bo = (M // bm, N // bn), M, bm
+        tile = pl.BlockSpec((bm, bn), lambda i, k: (i, k))
+        f_spec = pl.BlockSpec((bn, r), lambda i, k: (k, 0))
+        kernel = _proj_kernel
     with_e = e is not None
-    in_specs = [pl.BlockSpec((bm, bn), lambda i, k: (k, i))]
-    ins = [d]
-    if with_e:
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, k: (k, i)))
-        ins.append(e)
-    in_specs.append(pl.BlockSpec((bm, r), lambda i, k: (k, 0)))
-    ins.append(p)
+    ins = [d, e, f] if with_e else [d, f]
     return pl.pallas_call(
-        functools.partial(_proj_t_pack_kernel, n_k=gk, with_e=with_e,
-                          block=block),
-        grid=(gn, gk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((bn, r), lambda i, k: (i, 0)),
-            pl.BlockSpec((nblk_tile, block // 2), lambda i, k: (i, 0)),
-            pl.BlockSpec((nblk_tile,), lambda i, k: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, r), jnp.float32),
-            jax.ShapeDtypeStruct((N * r // block, block // 2), jnp.uint8),
-            jax.ShapeDtypeStruct((N * r // block,), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((r, bn), jnp.float32)],
-        interpret=interpret,
+        functools.partial(kernel, n_k=grid[1], with_e=with_e),
+        grid=grid,
+        in_specs=[tile] * (len(ins) - 1) + [f_spec],
+        out_specs=pl.BlockSpec((bo, r), lambda i, k: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, r), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bo, r), jnp.float32)],
+        interpret=kernels.pallas_interpret(),
     )(*ins)
 
 
 # ---------------------------------------------------------------------------
-# kernel 3: decompress dual + EF residual, from the packed factors
+# kernel 5: decompress dual + EF residual
 # ---------------------------------------------------------------------------
 
-def _dequant_tile(packed, scales, rows: int, r: int, block: int):
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
-    codes = jnp.stack([lo, hi], axis=2).reshape(packed.shape[0], block)
-    codes = jnp.where(codes >= 8, codes - 16, codes)
-    return (codes.astype(jnp.float32) * scales[:, None]).reshape(rows, r)
-
-
-def _recon_kernel(*refs, block: int, r: int, bm: int, bn: int,
-                  with_e: bool, with_ef: bool):
-    if with_ef:
-        if with_e:
-            (pp_ref, sp_ref, pq_ref, sq_ref, d_ref, e_ref, hat_ref,
-             enew_ref) = refs
-        else:
-            pp_ref, sp_ref, pq_ref, sq_ref, d_ref, hat_ref, enew_ref = refs
+def _recon_kernel(*refs, with_e: bool, with_ef: bool):
+    p_ref, q_ref = refs[:2]
+    rec = jax.lax.dot_general(p_ref[...], q_ref[...],
+                              (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32,
+                              precision=kernels.F32)
+    if not with_ef:
+        refs[2][...] = rec.astype(refs[2].dtype)
+        return
+    if with_e:
+        d_ref, e_ref, hat_ref, enew_ref = refs[2:]
     else:
-        pp_ref, sp_ref, pq_ref, sq_ref, hat_ref = refs
-    P = _dequant_tile(pp_ref[...], sp_ref[...], bm, r, block)
-    Q = _dequant_tile(pq_ref[...], sq_ref[...], bn, r, block)
-    rec = jax.lax.dot_general(P, Q, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+        (d_ref, hat_ref, enew_ref), e_ref = refs[2:], None
     hat_ref[...] = rec.astype(hat_ref.dtype)
-    if with_ef:
-        m_tile = d_ref[...].astype(jnp.float32)
-        if with_e:
-            m_tile = m_tile + e_ref[...]
-        enew_ref[...] = m_tile - rec
+    enew_ref[...] = _operand(d_ref, e_ref) - rec
 
 
-def _reconstruct(pp, sp, pq, sq, d, e, M: int, N: int, r: int,
-                 bm: int, bn: int, block: int, out_dtype,
-                 with_ef: bool, interpret: bool):
-    """d/e pre-padded to (M, N) or None.  Packed/scales padded to the tile
-    grid.  Returns hat (M, N) out_dtype, and e_new (M, N) f32 if with_ef."""
-    gm, gn = M // bm, N // bn
-    nblk_p, nblk_q = bm * r // block, bn * r // block
+def _reconstruct(pq, qq, d, e, bm: int, bn: int, out_dtype, with_ef: bool):
+    """pq (M, r) / qq (N, r): dequantized factors padded to the tile grid;
+    d/e the padded operands (used only when ``with_ef``).  Returns
+    (hat (M, N) out_dtype, e_new (M, N) f32 or None)."""
+    M, N = pq.shape[0], qq.shape[0]
+    r = pq.shape[1]
     with_e = e is not None
-    in_specs = [
-        pl.BlockSpec((nblk_p, block // 2), lambda i, j: (i, 0)),
-        pl.BlockSpec((nblk_p,), lambda i, j: (i,)),
-        pl.BlockSpec((nblk_q, block // 2), lambda i, j: (j, 0)),
-        pl.BlockSpec((nblk_q,), lambda i, j: (j,)),
-    ]
-    ins = [pp.reshape(M * r // block, block // 2), sp,
-           pq.reshape(N * r // block, block // 2), sq]
-    if with_ef:
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
-        ins.append(d)
-        if with_e:
-            in_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
-            ins.append(e)
-    out_specs = [pl.BlockSpec((bm, bn), lambda i, j: (i, j))]
+    tile = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
+    in_specs = [pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
+                pl.BlockSpec((bn, r), lambda i, j: (j, 0))]
+    ins = [pq, qq]
+    out_specs = [tile]
     out_shape = [jax.ShapeDtypeStruct((M, N), out_dtype)]
     if with_ef:
-        out_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
+        ins += [d, e] if with_e else [d]
+        in_specs += [tile] * (len(ins) - 2)
+        out_specs.append(tile)
         out_shape.append(jax.ShapeDtypeStruct((M, N), jnp.float32))
     out = pl.pallas_call(
-        functools.partial(_recon_kernel, block=block, r=r, bm=bm, bn=bn,
-                          with_e=with_e, with_ef=with_ef),
-        grid=(gm, gn),
+        functools.partial(_recon_kernel, with_e=with_e, with_ef=with_ef),
+        grid=(M // bm, N // bn),
         in_specs=in_specs,
-        out_specs=out_specs if with_ef else out_specs[0],
-        out_shape=out_shape if with_ef else out_shape[0],
-        interpret=interpret,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=kernels.pallas_interpret(),
     )(*ins)
-    return out if with_ef else (out, None)
+    return tuple(out) if with_ef else (out[0], None)
+
+
+def _dequant_factor(packed, scales, rows: int, rows_pad: int, r: int,
+                    block: int) -> jnp.ndarray:
+    f = quant4_unpack_pallas(packed, scales, rows * r, block)
+    return jnp.pad(f.reshape(rows, r), ((0, rows_pad - rows), (0, 0)))
 
 
 # ---------------------------------------------------------------------------
 # drivers
 # ---------------------------------------------------------------------------
 
-def _pad_packed(packed, scales, rows_pad: int, r: int, block: int):
-    """Zero-pad a ref-layout flat payload out to the tile grid (zero rows
-    quantize to zero codes with scale 0 -> dequant exactly 0)."""
-    want_b, want_s = rows_pad * r // 2, rows_pad * r // block
-    packed = jnp.pad(packed, (0, want_b - packed.shape[0]))
-    scales = jnp.pad(scales, (0, want_s - scales.shape[0]))
-    return packed, scales
-
-
 def fused_compress_ef(delta: jnp.ndarray,
                       error: Optional[jnp.ndarray],
                       q_prev: jnp.ndarray,
                       rank_scalar=None, *,
                       block: int = 256,
-                      row_cap: int = 2048,
-                      interpret: bool = True,
+                      row_cap: int = 512,
                       compute_error: bool = True,
                       ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray],
                                  jnp.ndarray, FusedPayload]:
@@ -344,78 +260,49 @@ def fused_compress_ef(delta: jnp.ndarray,
     """
     m, n = delta.shape
     r = q_prev.shape[1]
-    out_dtype = delta.dtype
-    if block % 2:
-        raise ValueError(f"block must be even, got {block}")
     cm = _col_mask(r, rank_scalar)
     qm = q_prev.astype(jnp.float32) * cm
 
-    bm = _row_tile(m, r, block, row_cap)
-    bn = _row_tile(n, r, block, row_cap)
-    # EF hoist (CPU lane): materialize the corrected delta once and feed
-    # every kernel with_e=False — interpret mode would re-pay the (m, n)
-    # add per kernel, which costs more than one materialization here.  On
-    # TPU (HBM-traffic-bound) flip this to keep the add fused in VMEM;
-    # the kernels' with_e path is what carries to hardware.
-    if error is not None:
-        delta = delta.astype(jnp.float32) + error.astype(jnp.float32)
+    bm, bn = _tiles(m, n, r, row_cap)
     d = _pad2d(delta, bm, bn)
-    e = None
+    e = None if error is None else _pad2d(error.astype(jnp.float32), bm, bn)
     M_pad, N_pad = d.shape
-    qm_p = jnp.pad(qm, ((0, N_pad - n), (0, 0)))
 
     # 1) P projection (EF add fused), 2) host r x r orthonormalize + mask
     from repro.core.compression import _orthonormalize
-    P = _project(d, e, qm_p, bm, bn, interpret)
+    P = _project(d, e, jnp.pad(qm, ((0, N_pad - n), (0, 0))), bm, bn,
+                 transpose=False)[:m]
     P = _orthonormalize(P) * cm
-
-    # 3) Q projection with in-flush quantize+pack; P packed by the quant4
-    #    kernel (its projection/quantization are separated by the host QR)
-    from repro.kernels.quant4 import quant4_pack_pallas
-    Q, packed_q, scales_q = _project_t_pack(d, e, P, bm, bn, block,
-                                            interpret)
-    n_rows_p = M_pad * r // block
-    packed_p, scales_p = quant4_pack_pallas(
-        P.reshape(-1), block, rows_per_tile=min(n_rows_p, 4096),
-        interpret=interpret)
-
-    # 4) fused decompress + EF residual from the packed payload
-    hat_pad, enew_pad = _reconstruct(
-        packed_p, scales_p, packed_q, scales_q, d, e, M_pad, N_pad, r,
-        bm, bn, block, out_dtype, with_ef=compute_error,
-        interpret=interpret)
-    delta_hat = hat_pad[:m, :n]
-    e_new = enew_pad[:m, :n] if compute_error else None
+    # 3) Q projection; 4) wire payload of both factors and its dequant
+    Q = _project(d, e, jnp.pad(P, ((0, M_pad - m), (0, 0))), bm, bn,
+                 transpose=True)[:n]
+    packed_p, scales_p = quant4_pack_pallas(P.reshape(-1), block)
+    packed_q, scales_q = quant4_pack_pallas(Q.reshape(-1), block)
+    # 5) fused decompress + EF residual from the packed payload
+    hat, e_new = _reconstruct(
+        _dequant_factor(packed_p, scales_p, m, M_pad, r, block),
+        _dequant_factor(packed_q, scales_q, n, N_pad, r, block),
+        d, e, bm, bn, delta.dtype, with_ef=compute_error)
 
     # warm start: keep the unquantized Q; zero-input guard as in the ref
     # chain (the first delayed round's all-zero delta must not wipe it)
-    Qs = Q[:n]
-    q_new = jnp.where(jnp.sum(Qs * Qs) > 0, Qs, qm)
-
-    # payload in the ref layout: flat prefix of the padded factors (the
-    # grid padding rows are exactly zero, matching the ref's block pad)
-    nb_p, nb_q = -(-m * r // block), -(-n * r // block)
-    payload = FusedPayload(
-        packed_p=packed_p[:nb_p * (block // 2)],
-        scales_p=scales_p[:nb_p],
-        packed_q=packed_q.reshape(-1)[:nb_q * (block // 2)],
-        scales_q=scales_q[:nb_q],
-        p_factor=P[:m], q_factor=Qs)
-    return delta_hat, e_new, q_new, payload
+    q_new = jnp.where(jnp.sum(Q * Q) > 0, Q, qm)
+    payload = FusedPayload(packed_p=packed_p, scales_p=scales_p,
+                           packed_q=packed_q, scales_q=scales_q,
+                           p_factor=P, q_factor=Q)
+    return (hat[:m, :n], None if e_new is None else e_new[:m, :n], q_new,
+            payload)
 
 
 def fused_decompress(packed_p, scales_p, packed_q, scales_q,
                      m: int, n: int, r: int, *,
-                     block: int = 256, row_cap: int = 2048,
-                     out_dtype=jnp.float32,
-                     interpret: bool = True) -> jnp.ndarray:
-    """Decompress dual: unpack -> dequant -> P Q^T, one fused kernel."""
-    bm = _row_tile(m, r, block, row_cap)
-    bn = _row_tile(n, r, block, row_cap)
-    M_pad, N_pad = _ceil_to(m, bm), _ceil_to(n, bn)
-    pp, sp = _pad_packed(packed_p, scales_p, M_pad, r, block)
-    pq, sq = _pad_packed(packed_q, scales_q, N_pad, r, block)
-    hat, _ = _reconstruct(pp, sp, pq, sq, None, None, M_pad, N_pad, r,
-                          bm, bn, block, out_dtype, with_ef=False,
-                          interpret=interpret)
+                     block: int = 256, row_cap: int = 512,
+                     out_dtype=jnp.float32) -> jnp.ndarray:
+    """Decompress dual: unpack -> dequant -> P Q^T, the reconstruct kernel
+    of ``fused_compress_ef`` on the same tiles."""
+    bm, bn = _tiles(m, n, r, row_cap)
+    hat, _ = _reconstruct(
+        _dequant_factor(packed_p, scales_p, m, _ceil_to(m, bm), r, block),
+        _dequant_factor(packed_q, scales_q, n, _ceil_to(n, bn), r, block),
+        None, None, bm, bn, out_dtype, with_ef=False)
     return hat[:m, :n]

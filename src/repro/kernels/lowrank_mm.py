@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -25,7 +27,8 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
 
     acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
                             b_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=kernels.F32)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -41,8 +44,7 @@ def _pad_to(x, m0, m1):
 
 
 def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
-                  bn: int = 128, bk: int = 128,
-                  interpret: bool = True) -> jnp.ndarray:
+                  bn: int = 128, bk: int = 128) -> jnp.ndarray:
     """(m,k) @ (k,n) -> (m,n), f32 accumulation, MXU-aligned tiles."""
     m, k = a.shape
     k2, n = b.shape
@@ -62,6 +64,6 @@ def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((ap.shape[0], bp.shape[1]), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(ap, bp)
     return out[:m, :n]

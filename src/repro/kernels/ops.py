@@ -2,9 +2,10 @@
 when available/enabled and to the pure-jnp reference otherwise (CPU tests,
 and the GSPMD dry-run where the kernel is a per-shard local op).
 
-Set ``REPRO_USE_PALLAS=1`` (or pass use_pallas=True) to route through
-``pl.pallas_call`` in interpret mode on CPU — the kernel tests sweep both
-paths and assert they agree with ref.py.
+Set ``REPRO_USE_PALLAS=1`` to route through
+``pl.pallas_call`` — interpreted on the CPU, compiled on a TPU
+(``kernels.pallas_interpret``); the kernel tests sweep both paths and
+assert they agree with ref.py.
 """
 from __future__ import annotations
 

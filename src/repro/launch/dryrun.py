@@ -194,20 +194,6 @@ def _analyze(compiled, n_chips: int, cluster_size: int = 0):
     return out
 
 
-def _train_shardings(cfg, mesh, n_clusters, p_specs, o_specs, b_specs):
-    ps = sh.param_shardings(p_specs, mesh, cluster_stacked=True)
-    os_ = jax.tree.map(
-        lambda x: (NamedSharding(mesh, P())
-                   if x.ndim <= 1 else None), o_specs)
-    # opt m/v mirror params; step counters replicated
-    m_sh = sh.param_shardings(p_specs, mesh, cluster_stacked=True)
-    opt_sh = type(o_specs)(step=jax.tree.map(
-        lambda _: NamedSharding(mesh, P("clusters")), o_specs.step),
-        m=m_sh, v=m_sh)
-    bs = sh.batch_shardings(b_specs, mesh, cluster_stacked=True)
-    return ps, opt_sh, bs
-
-
 def _lower_train(cfg, shape, base, rank, include_outer, mode):
     n_clusters = 2 if base.devices.ndim == 3 else 2
     mesh = mesh_lib.make_cluster_mesh(base, n_clusters=n_clusters)
@@ -216,8 +202,9 @@ def _lower_train(cfg, shape, base, rank, include_outer, mode):
     p_specs = steps.params_specs(cfg, n_clusters=n_clusters)
     o_specs = steps.opt_specs(p_specs)
     b_specs = steps.input_specs(cfg, shape, n_clusters=n_clusters)
-    ps, opt_sh, bs = _train_shardings(cfg, mesh, n_clusters, p_specs,
-                                      o_specs, b_specs)
+    ccfg = mc.MeshCompressionConfig(rank=rank)
+    ps, opt_sh, ost_sh = steps.round_shardings(cfg, mesh, n_clusters, ccfg)
+    bs = sh.batch_shardings(b_specs, mesh, cluster_stacked=True)
 
     train_step = steps.make_train_step(cfg)
     M.set_activation_sharder(sh.make_activation_sharder(mesh))
@@ -235,22 +222,8 @@ def _lower_train(cfg, shape, base, rank, include_outer, mode):
           % (ca.get("flops", 0), ca.get("bytes accessed", 0)))
 
     if include_outer:
-        ccfg = mc.MeshCompressionConfig(rank=rank)
-        ost_specs = jax.eval_shape(
-            lambda pp: steps.init_outer_state(pp, n_clusters, ccfg),
-            steps.params_specs(cfg))
+        ost_specs = steps.outer_state_specs(cfg, n_clusters, ccfg)
         outer_step = steps.make_outer_step(cfg, ccfg)
-        p_unstacked = steps.params_specs(cfg)
-        ps_un = sh.param_shardings(p_unstacked, mesh, cluster_stacked=False)
-        ost_sh = steps.OuterState(
-            anchor=ps_un,
-            outer_opt=jax.eval_shape(lambda: None) if False else
-            _nesterov_shardings(p_unstacked, mesh),
-            delta_pending=sh.param_shardings(p_specs, mesh,
-                                             cluster_stacked=True),
-            error=sh.param_shardings(p_specs, mesh, cluster_stacked=True),
-            q_state=_qstate_shardings(ost_specs.q_state, mesh),
-        )
         lowered_o = jax.jit(
             outer_step,
             in_shardings=(ps, ost_sh, NamedSharding(mesh, P())),
@@ -261,27 +234,6 @@ def _lower_train(cfg, shape, base, rank, include_outer, mode):
         out["outer"] = _analyze(compiled_o, n_chips, cluster_size)
         print("outer memory_analysis:", compiled_o.memory_analysis())
     return out
-
-
-def _nesterov_shardings(p_specs, mesh):
-    from repro.optim import nesterov as nv
-    st = jax.eval_shape(nv.init, p_specs)
-    mom = sh.param_shardings(p_specs, mesh, cluster_stacked=False)
-    return type(st)(step=NamedSharding(mesh, P()), momentum=mom)
-
-
-def _qstate_shardings(q_specs, mesh):
-    def build(leaf):
-        if leaf.ndim <= 1:
-            return NamedSharding(mesh, P())
-        dims = [None] * leaf.ndim
-        dims[0] = "clusters" if leaf.shape[0] % mesh.shape["clusters"] == 0 \
-            else None
-        # shard the n dim (second to last) over data, like params
-        if leaf.ndim >= 3 and leaf.shape[-2] % mesh.shape["data"] == 0:
-            dims[-2] = "data"
-        return NamedSharding(mesh, P(*dims))
-    return jax.tree.map(build, q_specs)
 
 
 def _lower_train_pipeline(cfg, shape, base, n_micro=16):

@@ -9,11 +9,45 @@ cluster ("data", "model") axes.
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+import os
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+_HOST_FLAG = "--xla_force_host_platform_device_count"
+
+
+def request_host_devices(n: Optional[int]) -> None:
+    """CPU test path: ask XLA's host platform for ``n`` devices.  Must run
+    before jax initializes a backend; an accelerator ignores the flag, and
+    ``launcher_mesh`` then holds ``n`` against the real device count."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if n and _HOST_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {_HOST_FLAG}={n}".strip()
+
+
+def launcher_mesh(shape: Sequence[int], axes: Sequence[str],
+                  devices: Optional[int] = None) -> Mesh:
+    """The launchers' mesh over the devices jax sees.  ``devices`` (the
+    ``--devices`` flag) must agree with their count when given.  A mesh
+    smaller than the host takes its first devices (a 2-chip run on a
+    4-chip host); one process drives every chip either way.  Axes are
+    ``Auto``: the launchers place arrays with GSPMD sharding rules and
+    constraints, which jax 0.9's default ``Explicit`` axes reject."""
+    devs = jax.devices()
+    where = f"{len(devs)} {devs[0].platform} device(s)"
+    if devices is not None and devices != len(devs):
+        raise SystemExit(f"--devices {devices} disagrees with the {where} "
+                         f"jax sees")
+    n = math.prod(shape)
+    if n > len(devs):
+        raise SystemExit(f"mesh {dict(zip(axes, shape))} needs {n} "
+                         f"devices; jax sees {where}")
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

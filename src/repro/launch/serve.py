@@ -2,8 +2,14 @@
 continuous-batching engine (``--paged``; dense/moe GQA stacks), loading
 DiLoCoX-trainer checkpoints via ``repro.checkpoint``.
 
-  python -m repro.launch.serve --arch gemma3-1b --smoke --devices 4 \
+  python -m repro.launch.serve --arch gemma3-1b --smoke \
       --batch 4 --prompt-len 16 --gen-len 16 [--paged] [--ckpt DIR|PATH]
+
+The serving mesh is (data, model) over ``jax.devices()`` (default 1 x 1:
+one chip, or the one CPU device); ``--devices`` (if given) must match the
+device count, and on the CPU it asks XLA for that many fake host devices.
+``run(args, cfg)`` serves an already-built config and returns the run's
+statistics (``chip_smoke.py`` calls it at OPT-1.3B width).
 
 Throughput is reported per phase — prefill tok/s (prompt tokens absorbed
 into the cache) and decode tok/s (tokens actually generated) — plus the
@@ -18,13 +24,16 @@ import os
 import sys
 
 
-def _parse_args():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--devices", type=int, default=4)
-    ap.add_argument("--data", type=int, default=2)
-    ap.add_argument("--model", type=int, default=2)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="expected device count (default: every device jax "
+                         "sees); on the CPU, the number of fake host "
+                         "devices to create")
+    ap.add_argument("--data", type=int, default=1)
+    ap.add_argument("--model", type=int, default=1)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=16)
@@ -53,7 +62,7 @@ def _parse_args():
     ap.add_argument("--metrics-out", default="",
                     help="write repro_serve_* metrics (Prometheus text)")
     ap.add_argument("--log-json", action="store_true")
-    return ap.parse_args()
+    return ap
 
 
 def _load_params(path, params_like, log):
@@ -92,19 +101,28 @@ def _load_params(path, params_like, log):
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def main() -> None:
-    args = _parse_args()
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    from repro.launch.mesh import request_host_devices
+    request_host_devices(args.devices)
 
-    import time
+    from repro import compile_cache
+    from repro.configs.base import get_config
+    compile_cache.enable()
+    cfg = get_config(args.arch)
+    st = run(args, cfg.reduced() if args.smoke else cfg)
+    if st.get("unsupported"):
+        sys.exit(2)
 
+
+def run(args, cfg) -> dict:
+    """Serve ``cfg`` as ``args`` describes and return the run's
+    statistics (the paged engine's ``stats()`` plus ``"generated"``, each
+    request's tokens in submission order, on the paged path)."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs.base import get_config
-    from repro.launch import steps
+    from repro.launch.mesh import launcher_mesh
     from repro.models import model as M
     from repro.obs import (MetricsRegistry, Tracer, configure_logging,
                            get_logger)
@@ -121,12 +139,10 @@ def main() -> None:
             return contextlib.nullcontext()
     metrics = MetricsRegistry() if args.metrics_out else None
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced()
     eos = cfg.eos_id if args.eos is None else (
         None if args.eos < 0 else args.eos)
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = launcher_mesh((args.data, args.model), ("data", "model"),
+                         args.devices)
     M.set_activation_sharder(sh.make_activation_sharder(mesh))
 
     params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -138,10 +154,10 @@ def main() -> None:
                                  args.prompt_len), 0, cfg.vocab_size)
 
     if args.paged:
-        _run_paged(args, cfg, params, np.asarray(prompt), eos, log, span,
-                   metrics)
+        st = _run_paged(args, cfg, params, np.asarray(prompt), eos, log,
+                        span, metrics)
     else:
-        _run_dense(args, cfg, params, prompt, eos, log, span, metrics)
+        st = _run_dense(args, cfg, params, prompt, eos, log, span, metrics)
 
     if tracer is not None:
         tracer.write(args.trace)
@@ -149,9 +165,10 @@ def main() -> None:
     if metrics is not None:
         metrics.write_prometheus(args.metrics_out)
         log.info(f"wrote {args.metrics_out}")
+    return st
 
 
-def _run_dense(args, cfg, params, prompt, eos, log, span, metrics) -> None:
+def _run_dense(args, cfg, params, prompt, eos, log, span, metrics) -> dict:
     import time
 
     import jax
@@ -218,15 +235,17 @@ def _run_dense(args, cfg, params, prompt, eos, log, span, metrics) -> None:
         metrics.counter("repro_serve_prefill_tokens").inc(prefill_toks)
         metrics.counter("repro_serve_decode_tokens").inc(decode_toks)
     print("SERVE-DRIVER-OK")
+    return {"prefill_tokens": prefill_toks, "decode_tokens": decode_toks,
+            "wall_s": t2 - t0, "generated": gen}
 
 
-def _run_paged(args, cfg, params, prompts, eos, log, span, metrics) -> None:
+def _run_paged(args, cfg, params, prompts, eos, log, span, metrics) -> dict:
     from repro.serve.engine import ServeEngine, supports_paged
 
     ok, why = supports_paged(cfg)
     if not ok:
         print(f"SERVE-DRIVER-UNSUPPORTED: {args.arch}: {why}")
-        sys.exit(2)
+        return {"unsupported": why}
 
     ps = args.page_size
     max_new = args.gen_len
@@ -239,7 +258,10 @@ def _run_paged(args, cfg, params, prompts, eos, log, span, metrics) -> None:
     n_req = args.requests or args.batch
     for r in range(n_req):
         engine.submit(prompts[r].tolist(), max_new, arrival=0)
+    built = engine.compile()
+    print(f"compiled paged decode step in {built['compile_s']:.1f}s")
     st = engine.run()
+    st.update(built)
 
     print(f"paged engine: {st['requests_done']} requests in {st['steps']} "
           f"steps ({args.policy}, backend={args.backend}, "
@@ -262,6 +284,9 @@ def _run_paged(args, cfg, params, prompts, eos, log, span, metrics) -> None:
     print("sample:", done[0].generated[:12] if done else [])
     print(f"admission fingerprint: {st['admission_fingerprint']}")
     print("SERVE-DRIVER-OK")
+    st["generated"] = [r.generated for r in done]
+    st["prompts"] = [r.prompt for r in done]
+    return st
 
 
 if __name__ == "__main__":

@@ -17,6 +17,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import mesh_compression as mc
@@ -230,3 +231,43 @@ def outer_state_specs(cfg: ModelConfig, n_clusters: int,
     p = params_specs(cfg)
     return jax.eval_shape(
         lambda pp: init_outer_state(pp, n_clusters, ccfg), p)
+
+
+# ---------------------------------------------------------------------------
+# placement of the round state (shared by the trainer and the dry-run)
+# ---------------------------------------------------------------------------
+
+def round_shardings(cfg: ModelConfig, mesh: Mesh, n_clusters: int,
+                    ccfg: mc.MeshCompressionConfig):
+    """NamedShardings ``(params, opt, outer_state)`` of the GSPMD round
+    state on a (clusters, data, model) mesh.  Passing them as both the in-
+    and out-shardings of the jitted steps keeps one executable per step
+    (and lets donation update the state in place)."""
+    from repro.parallel import sharding as sh
+
+    p_specs = params_specs(cfg, n_clusters=n_clusters)
+    ps = sh.param_shardings(p_specs, mesh, cluster_stacked=True)
+    opt_sh = adamw.AdamWState(step=NamedSharding(mesh, P("clusters")),
+                              m=ps, v=ps)
+    anchor = sh.param_shardings(params_specs(cfg), mesh,
+                                cluster_stacked=False)
+
+    def q_sharding(leaf):
+        if leaf.ndim <= 1:
+            return NamedSharding(mesh, P())
+        dims = [None] * leaf.ndim
+        if leaf.shape[0] % mesh.shape["clusters"] == 0:
+            dims[0] = "clusters"
+        # the n dim (second to last) over data, like the params
+        if leaf.ndim >= 3 and leaf.shape[-2] % mesh.shape["data"] == 0:
+            dims[-2] = "data"
+        return NamedSharding(mesh, P(*dims))
+
+    ost = outer_state_specs(cfg, n_clusters, ccfg)
+    ost_sh = OuterState(
+        anchor=anchor,
+        outer_opt=nesterov.NesterovState(step=NamedSharding(mesh, P()),
+                                         momentum=anchor),
+        delta_pending=ps, error=ps,
+        q_state=jax.tree.map(q_sharding, ost.q_state))
+    return ps, opt_sh, ost_sh

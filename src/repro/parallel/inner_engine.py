@@ -47,7 +47,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import diloco
@@ -86,7 +86,8 @@ def unit_mesh(pcfg: PP.PipelineConfig, data_parallel: int = 1) -> Mesh:
             f"but jax sees {jax.device_count()}; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before jax "
             f"initializes")
-    return jax.make_mesh((data_parallel, pcfg.n_stages), ("data", "model"))
+    return jax.make_mesh((data_parallel, pcfg.n_stages), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def init_train_state(cfg: ModelConfig, pcfg: PP.PipelineConfig,
@@ -129,13 +130,6 @@ def state_shardings(state: DiLoCoTrainState, mesh: Mesh, *,
             step=scalar, momentum=pshard(state.outer_opt.momentum)),
         error=pshard(state.error),
     )
-
-
-def shard_train_state(state: DiLoCoTrainState, mesh: Mesh, *,
-                      cluster_stacked: bool = False) -> DiLoCoTrainState:
-    """Place a host-built state onto the mesh under ``state_shardings``."""
-    return jax.device_put(
-        state, state_shardings(state, mesh, cluster_stacked=cluster_stacked))
 
 
 # ---------------------------------------------------------------------------

@@ -35,19 +35,6 @@ from repro.models import model as M
 from repro.models.layers import apply_norm, embed_init, dense_init, init_norm, split
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax>=0.6 exposes ``jax.shard_map``
-    (``check_vma=``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (``check_rep=``). Replication checking is off in both spellings — the
-    per-stage loss masking here is deliberately "unreplicated"."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 @dataclass(frozen=True)
 class PipelineConfig:
     n_stages: int = 4
@@ -89,6 +76,20 @@ def init_pp_params(cfg: ModelConfig, rng, pcfg: PipelineConfig):
     if not cfg.tie_embeddings:
         params["head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size, dt)
     return params
+
+
+def sequential_params(params, cfg: ModelConfig):
+    """The unpipelined model's params (``models.model`` layout) holding the
+    same weights as one cluster's pipeline params: the stage dim is
+    flattened back into the layer stack and padded slots are dropped.  The
+    reference side of the pipeline-vs-sequential loss comparisons."""
+    stack = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]),
+                         params["stages"])
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "segments": [jax.tree.map(lambda x: x[:cfg.n_layers], stack)]}
+    if "head" in params:
+        out["head"] = params["head"]
+    return out
 
 
 def pp_param_specs(params, mesh: Mesh, *, cluster_stacked: bool):
@@ -218,8 +219,10 @@ def make_pp_loss(cfg: ModelConfig, mesh: Mesh, pcfg: PipelineConfig, *,
         mesh, cluster_stacked=cluster_stacked),
         P(*( ("clusters", "data", None) if cluster_stacked
              else ("data", None))))
-    loss_sm = _shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                         out_specs=P())
+    # replication checking is off: the per-stage loss masking here is
+    # deliberately "unreplicated"
+    loss_sm = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(), check_vma=False)
     return loss_sm
 
 

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.models import attention as attn
 
 NEG_INF = attn.NEG_INF
@@ -101,7 +102,8 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     qg = q.reshape(kv, g, dh)
     # scores (KV, G, ps): batch over KV, contract dh
     sc = jax.lax.dot_general(qg, k, (((2,), (2,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32) * scale
+                             preferred_element_type=jnp.float32,
+                             precision=kernels.F32) * scale
     q_pos = len_ref[s]
     k_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (kv, g, ps), 2)
     ok = k_pos <= q_pos
@@ -118,7 +120,8 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     l_ref[...] = l_prev * alpha + pexp.sum(axis=-1)
     # (KV, G, ps) @ (ps, KV, dh) batched over KV -> (KV, G, dh)
     pv = jax.lax.dot_general(pexp, v, (((2,), (0,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=kernels.F32)
     acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
     m_ref[...] = m_new
 
@@ -129,7 +132,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def pallas_paged_attention(q, cache, page_tables, lengths, *,
-                           window: int = 0, interpret: bool = True):
+                           window: int = 0):
     """Same contract as ``ref_paged_attention`` (ulp-bounded, not bitwise:
     the online-softmax recurrence reassociates the reduction)."""
     S, _, H, dh = q.shape
@@ -160,7 +163,7 @@ def pallas_paged_attention(q, cache, page_tables, lengths, *,
                           scale=scale, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, dh), q.dtype),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(page_tables, lengths, q.reshape(S, H, dh), cache["k"], cache["v"])
     return out.reshape(S, 1, H, dh)
 
@@ -170,11 +173,11 @@ def pallas_paged_attention(q, cache, page_tables, lengths, *,
 # ---------------------------------------------------------------------------
 
 def paged_attention(q, cache, page_tables, lengths, *, window: int = 0,
-                    backend: str = "ref", interpret: bool = True):
+                    backend: str = "ref"):
     if backend == "ref":
         return ref_paged_attention(q, cache, page_tables, lengths,
                                    window=window)
     if backend == "pallas":
         return pallas_paged_attention(q, cache, page_tables, lengths,
-                                      window=window, interpret=interpret)
+                                      window=window)
     raise ValueError(f"unknown paged-attention backend {backend!r}")

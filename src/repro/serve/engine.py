@@ -81,7 +81,12 @@ def dense_kv_bytes(cfg: ModelConfig, *, n_seqs: int, s_max: int,
             * cfg.resolved_head_dim * 2 * dt.itemsize)
 
 
-def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref"):
+def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
+                           return_logits: bool = False):
+    """The engine's device step: one token per slot through every layer,
+    writing its K/V into the pages.  Returns ``(next_tokens, caches)``,
+    and the step's logits ``(max_seqs, vocab)`` last when
+    ``return_logits``."""
     ok, why = supports_paged(cfg)
     if not ok:
         raise NotImplementedError(why)
@@ -129,6 +134,8 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref"):
             new_caches.append(nc)
         logits = M.logits_fn(params, cfg, x1)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        if return_logits:
+            return nxt, new_caches, logits[:, -1]
         return nxt, new_caches
 
     return step
@@ -147,14 +154,17 @@ class ServeEngine:
     (equivalence tests / fixed-length load traces). ``step_fn`` lets
     callers share one jitted executable across engines (the benchmark's
     continuous-vs-static fairness: identical compiled step, only the
-    admission policy differs).
+    admission policy differs).  ``keep_logits`` copies every step's logits
+    to the host: ``self.logits[rid][pos]`` is the row that predicted
+    token ``pos + 1`` of request ``rid`` (a check against a dense
+    forward, at the cost of one device-to-host copy per step).
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_seqs: int,
                  page_size: int, n_pages: int, max_pages_per_seq: int,
                  backend: str = "ref", eos_id: Any = "cfg",
                  policy: str = "continuous", dtype=None, step_fn=None,
-                 metrics=None, span=None):
+                 metrics=None, span=None, keep_logits: bool = False):
         ok, why = supports_paged(cfg)
         if not ok:
             raise NotImplementedError(f"{cfg.name}: {why}")
@@ -172,8 +182,10 @@ class ServeEngine:
         self.caches = init_kv_pages(cfg, n_pages=n_pages,
                                     page_size=page_size, dtype=dtype)
         self._fn = step_fn if step_fn is not None else jax.jit(
-            make_paged_decode_step(cfg, backend=backend),
+            make_paged_decode_step(cfg, backend=backend,
+                                   return_logits=keep_logits),
             donate_argnums=(1,))
+        self.logits = {} if keep_logits else None
         self.step_count = 0
         self.prefill_tokens = 0
         self.decode_tokens = 0
@@ -196,6 +208,23 @@ class ServeEngine:
         self.sched.submit(req)
         return req
 
+    def compile(self) -> Dict[str, Any]:
+        """AOT-compile the decode step for this engine's shapes, so that
+        the first ``step`` runs it instead of compiling it.  Returns the
+        compile seconds and the executable's ``memory_analysis`` bytes."""
+        S = self.pages.max_seqs
+        zeros = jnp.zeros((S,), jnp.int32)
+        t = time.perf_counter()
+        self._fn = self._fn.lower(
+            self.params, self.caches, zeros, zeros, jnp.zeros((S,), bool),
+            jnp.asarray(self.pages.page_table)).compile()
+        seconds = time.perf_counter() - t
+        ma = self._fn.memory_analysis()
+        return {"compile_s": seconds,
+                "memory": {k: int(getattr(ma, k + "_size_in_bytes"))
+                           for k in ("argument", "output", "alias",
+                                     "temp")}}
+
     # -- stepping ----------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration (admit -> plan -> device step -> commit).
@@ -212,11 +241,16 @@ class ServeEngine:
             return True
         tokens, lengths, active = plan
         with self._span("device_step", n_active=int(active.sum())):
-            nxt, self.caches = self._fn(
+            nxt, self.caches, *logits = self._fn(
                 self.params, self.caches, jnp.asarray(tokens),
                 jnp.asarray(lengths), jnp.asarray(active),
                 jnp.asarray(self.pages.page_table))
             nxt = np.asarray(nxt)
+        if self.logits is not None:
+            rows = np.asarray(logits[0])
+            for i in np.flatnonzero(active):
+                self.logits.setdefault(sched.slots[i].req.rid, {})[
+                    int(lengths[i])] = rows[i]
         n_prefill = sum(1 for s in sched.slots
                         if s is not None and s.fed < len(s.req.prompt) - 1)
         n_active = int(active.sum())

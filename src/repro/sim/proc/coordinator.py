@@ -190,6 +190,11 @@ def _spawn(cluster: int, port: int, sc: Scenario, problem, gossip: bool,
     src = _src_root()
     env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else src)
+    # the process backend is a CPU model of a WAN fleet: workers run on the
+    # host's CPU, never on an accelerator the parent may hold (one process
+    # per chip), and the coordinator's own numeric work is pinned likewise
+    # (``run_proc``)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "repro.sim.proc.worker", json.dumps(cfg)],
         env=env)
@@ -216,7 +221,21 @@ def run_proc(sc: Scenario, problem=None, *,
     timing-only workers, which skip jax entirely).  ``crash_at`` maps
     cluster -> round for injected hard crashes (``os._exit`` before the
     delta send — the membership-recovery test hook).
+
+    Everything runs on the CPU: the workers by ``_spawn``'s environment,
+    the coordinator's gather-mean under a CPU default device.
     """
+    kw = dict(crash_at=crash_at, spawn_timeout_s=spawn_timeout_s,
+              round_timeout_s=round_timeout_s, p2p_timeout_s=p2p_timeout_s)
+    if problem is None:
+        return _run_proc(sc, None, **kw)
+    import jax
+    with jax.default_device(jax.devices("cpu")[0]):
+        return _run_proc(sc, problem, **kw)
+
+
+def _run_proc(sc: Scenario, problem, *, crash_at, spawn_timeout_s,
+              round_timeout_s, p2p_timeout_s) -> Timeline:
     from repro.core import adaptive as _ada
     from repro.core.compression import make_compressor
     from repro.sim.simulator import _jitter_factors

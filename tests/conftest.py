@@ -1,17 +1,12 @@
 """Tier-1 test configuration.
 
-Two jobs:
- 1. Make ``hypothesis`` optional: when the real package is missing, install
-    ``tests/_hypothesis_compat.py`` (a deterministic fixed-example fallback)
-    into ``sys.modules`` *before* collection, so the property-test modules
-    import cleanly and still run meaningful fixed-seed cases.
- 2. Keep tier-1 fast: tests marked ``@pytest.mark.slow`` (multi-minute
+ 1. Keep tier-1 fast: tests marked ``@pytest.mark.slow`` (multi-minute
     subprocess/integration runs) are skipped unless ``--runslow`` is given
     or an explicit ``-m slow`` selection asks for them.
+ 2. Compile once: XLA's backend optimization is turned down and jax's
+    persistent compilation cache is on (``repro.compile_cache``).
 """
-import importlib.util
 import os
-import sys
 
 import pytest
 
@@ -26,29 +21,19 @@ if not os.environ.get("REPRO_FULL_XLA_OPT"):
             _flags + " --xla_backend_optimization_level=0").strip()
 
 # --- persistent jax compilation cache --------------------------------------
-# The remaining tier-1 cost is the per-arch value_and_grad compiles; jax's
-# persistent compilation cache (works on CPU in 0.4.x via env vars alone)
-# makes re-runs skip them entirely.  Opt out with REPRO_NO_JAX_CACHE=1;
-# point JAX_COMPILATION_CACHE_DIR elsewhere to relocate (CI caches this
-# directory between runs in both tier-1 jobs).  Must be set before jax
-# initializes, hence here and not in a fixture.
+# The remaining tier-1 cost is the per-arch value_and_grad compiles; the
+# cache makes re-runs skip them.  Same rule as every entry point
+# (``repro.compile_cache``): $JAX_COMPILATION_CACHE_DIR when set, else the
+# checkout's git-ignored ``.jax_cache``; subprocess tests inherit it.  Opt
+# out with REPRO_NO_JAX_CACHE=1.
 if not os.environ.get("REPRO_NO_JAX_CACHE"):
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-jax-cache"))
     # default min-compile-time is 1 s; at 0.5 s the mid-sized jits (sim
-    # numeric rounds, compressor roundtrips) get cached too
+    # numeric rounds, compressor roundtrips) get cached too.  Set before
+    # ``compile_cache`` imports jax, which reads it once, at import
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                           "0.5")
-
-# --- hypothesis fallback (must happen at import time, before collection) ---
-if importlib.util.find_spec("hypothesis") is None:
-    _here = os.path.dirname(__file__)
-    if _here not in sys.path:
-        sys.path.insert(0, _here)
-    import _hypothesis_compat
-    sys.modules["hypothesis"] = _hypothesis_compat
-    sys.modules["hypothesis.strategies"] = _hypothesis_compat.strategies
+    from repro import compile_cache
+    compile_cache.enable()
 
 
 def pytest_addoption(parser):

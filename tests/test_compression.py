@@ -101,6 +101,22 @@ def test_rank_mask_matches_true_rank():
                                np.asarray(out_s["w"]), atol=2e-3)
 
 
+def test_warm_start_follows_the_matrix_not_the_stacking():
+    """A stacked leaf's leading dims (pipeline stages x layers per stage)
+    merge into one matrix: (2, 1, m, n) and (1, 2, m, n) compress the same
+    (2m, n) matrix and must start from the same Q."""
+    comp = C.LowRankQuant(rank=8, bits=4, min_dim_for_lowrank=8)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32))
+    a = comp.init_state({"w": x.reshape(2, 1, 16, 32)})["w"]
+    b = comp.init_state({"w": x.reshape(1, 2, 16, 32)})["w"]
+    assert a.shape == (32, 8)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    out_a, _ = comp.roundtrip({"w": x.reshape(2, 1, 16, 32)}, {"w": a})
+    out_b, _ = comp.roundtrip({"w": x.reshape(1, 2, 16, 32)}, {"w": b})
+    np.testing.assert_array_equal(np.asarray(out_a["w"]).reshape(x.shape),
+                                  np.asarray(out_b["w"]).reshape(x.shape))
+
+
 # ---------------------------------------------------------------------------
 # wire-bytes accounting (feeds the 357x throughput model)
 # ---------------------------------------------------------------------------

@@ -44,7 +44,8 @@ def test_train_driver_adaptive():
 @pytest.mark.parametrize("arch", ["gemma3-1b", "zamba2-1.2b"])
 def test_serve_driver(arch):
     r = _run(["repro.launch.serve", "--arch", arch, "--smoke",
-              "--devices", "4", "--batch", "4", "--prompt-len", "8",
+              "--devices", "4", "--data", "2", "--model", "2",
+              "--batch", "4", "--prompt-len", "8",
               "--gen-len", "8"])
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-1500:]
     assert "SERVE-DRIVER-OK" in r.stdout
@@ -57,7 +58,8 @@ def test_serve_driver(arch):
 @pytest.mark.parametrize("arch", ["gemma3-1b", "granite-3-8b"])
 def test_serve_driver_paged(arch):
     r = _run(["repro.launch.serve", "--arch", arch, "--smoke",
-              "--devices", "4", "--batch", "3", "--prompt-len", "8",
+              "--devices", "4", "--data", "2", "--model", "2",
+              "--batch", "3", "--prompt-len", "8",
               "--gen-len", "8", "--paged", "--requests", "6"])
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-1500:]
     assert "SERVE-DRIVER-OK" in r.stdout
@@ -69,7 +71,8 @@ def test_serve_driver_paged(arch):
 def test_serve_driver_paged_unsupported_family():
     # zamba2 is a hybrid SSM stack: the paged engine must refuse cleanly
     r = _run(["repro.launch.serve", "--arch", "zamba2-1.2b", "--smoke",
-              "--devices", "4", "--batch", "2", "--prompt-len", "4",
+              "--devices", "4", "--data", "2", "--model", "2",
+              "--batch", "2", "--prompt-len", "4",
               "--gen-len", "4", "--paged"])
     assert r.returncode == 2, r.stdout[-1500:] + r.stderr[-1500:]
     assert "SERVE-DRIVER-UNSUPPORTED" in r.stdout

@@ -383,3 +383,12 @@ def test_paged_write_kv_routes_inactive_to_scratch():
     assert k[2, 1].all()                            # active row landed
     assert not k[3].any() and not k[4].any()        # inactive row did not
     assert k[0, 2].all()                            # ... it went to scratch
+
+
+def test_flash_attention_refuses_a_gradient():
+    """No backward kernel: differentiating says so instead of handing
+    Mosaic a gradient it cannot compile."""
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 2, 64))
+    loss = lambda q_: flash_attention_pallas(q_, q, q, bq=128, bk=128).sum()
+    with pytest.raises(NotImplementedError, match="forward-only"):
+        jax.grad(loss)(q)

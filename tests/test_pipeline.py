@@ -37,15 +37,7 @@ SCRIPT = textwrap.dedent("""
     loss_pp = float(jax.jit(loss_fn)(paramsC, tokens))
 
     def ref_loss_from_pp(pC):
-        p = jax.tree.map(lambda x: x[0], pC)
-        sp = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]),
-                          p["stages"])
-        # drop padded layers (active==0) from the sequential reference
-        sp = jax.tree.map(lambda x: x[:cfg.n_layers], sp)
-        rp = {"embed": p["embed"], "final_norm": p["final_norm"],
-              "segments": [sp]}
-        if "head" in p:
-            rp["head"] = p["head"]
+        rp = PP.sequential_params(jax.tree.map(lambda x: x[0], pC), cfg)
         return M.loss_fn(rp, cfg, {"tokens": tokens[0]}, remat=False)[0]
 
     ref = float(ref_loss_from_pp(paramsC))

@@ -276,6 +276,40 @@ def test_paged_continuous_staggered_matches_per_seq_dense():
             f"rid {req.rid}: {req.generated} != {dense[0].tolist()}"
 
 
+def test_paged_engine_kept_logits_match_the_dense_forward():
+    """``keep_logits``: every position's logits, staggered arrivals and
+    unequal prompts, equal the dense teacher-forced forward over the
+    request's own tokens, and the greedy tokens are their argmax."""
+    import jax
+
+    from repro.configs.base import get_config
+    from repro.models import model as M
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config("granite-3-8b").reduced()
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    ps, maxP, G = 4, 3, 5
+    rng = np.random.default_rng(3)
+    reqs = [(0, rng.integers(0, cfg.vocab_size, 3).tolist()),
+            (2, rng.integers(0, cfg.vocab_size, 6).tolist())]
+    eng = ServeEngine(params, cfg, max_seqs=2, page_size=ps,
+                      n_pages=2 * maxP, max_pages_per_seq=maxP, eos_id=None,
+                      keep_logits=True)
+    for arrival, prompt in reqs:
+        eng.submit(prompt, G, arrival=arrival)
+    eng.run()
+    for req in sorted(eng.sched.done, key=lambda r: r.rid):
+        seq = np.asarray([req.prompt + req.generated], np.int32)
+        h, _ = M.forward_hidden(params, cfg, {"tokens": seq[:, :-1]})
+        want = np.asarray(M.logits_fn(params, cfg, h))[0]
+        got = np.stack([req_rows for _, req_rows in
+                        sorted(eng.logits[req.rid].items())])
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        assert got[len(req.prompt) - 1:].argmax(-1).tolist() == \
+            req.generated
+
+
 def test_paged_engine_eos_and_backpressure_integration():
     """Tiny pool + EOS enabled: requests defer instead of OOMing, every
     request completes, no page leaks."""
