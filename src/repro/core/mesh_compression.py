@@ -74,10 +74,12 @@ def _compress_leaf_matrix(M, q_prev, rank_scalar, cfg: MeshCompressionConfig):
     else:
         col_mask = jnp.ones((r,), jnp.float32)
     P = kops.matmul(M, q_prev * col_mask)
-    P = _orthonormalize(P) * col_mask
+    with jax.named_scope("outer.orthonormalize"):
+        P = _orthonormalize(P) * col_mask
     Q = kops.matmul(M.T, P)
-    pP, sP = kops.quant4_pack(P.reshape(-1), cfg.block)
-    pQ, sQ = kops.quant4_pack(Q.reshape(-1), cfg.block)
+    with jax.named_scope("outer.quant"):
+        pP, sP = kops.quant4_pack(P.reshape(-1), cfg.block)
+        pQ, sQ = kops.quant4_pack(Q.reshape(-1), cfg.block)
     # zero-input guard (first delayed round): never zero the warm start
     q_new = jnp.where(jnp.sum(Q * Q) > 0, Q, q_prev * col_mask)
     return (pP, sP, pQ, sQ), q_new
@@ -85,16 +87,20 @@ def _compress_leaf_matrix(M, q_prev, rank_scalar, cfg: MeshCompressionConfig):
 
 def _decompress_leaf_matrix(payload, m, n, r, cfg: MeshCompressionConfig):
     pP, sP, pQ, sQ = payload
-    P = kops.quant4_unpack(pP, sP, m * r, cfg.block).reshape(m, r)
-    Q = kops.quant4_unpack(pQ, sQ, n * r, cfg.block).reshape(n, r)
+    with jax.named_scope("outer.quant"):
+        P = kops.quant4_unpack(pP, sP, m * r, cfg.block).reshape(m, r)
+        Q = kops.quant4_unpack(pQ, sQ, n * r, cfg.block).reshape(n, r)
     return kops.matmul(P, Q.T)
 
 
+@jax.named_scope("outer.compress")
 def compress_gather_mean(delta_stacked, q_state, rank_scalar,
                          cfg: MeshCompressionConfig):
     """delta_stacked: cluster-stacked pytree (C, ...). Returns
     (Delta mean tree (...), new q_state). The cross-cluster data movement is
-    the packed payload (uint8 + scales)."""
+    the packed payload (uint8 + scales).  Region ``outer.compress``, with
+    the Cholesky-QR in ``outer.orthonormalize`` and int4 pack/unpack in
+    ``outer.quant``."""
 
     def one(path, d, q):
         C = d.shape[0]
@@ -102,10 +108,12 @@ def compress_gather_mean(delta_stacked, q_state, rank_scalar,
         if q.size == 0:
             # quant-only: pack per cluster, unpack all, mean
             flat = d.reshape(C, -1).astype(jnp.float32)
-            pk, sc = jax.vmap(lambda v: kops.quant4_pack(v, cfg.block))(flat)
-            vals = jax.vmap(
-                lambda p, s: kops.quant4_unpack(p, s, flat.shape[1],
-                                                cfg.block))(pk, sc)
+            with jax.named_scope("outer.quant"):
+                pk, sc = jax.vmap(
+                    lambda v: kops.quant4_pack(v, cfg.block))(flat)
+                vals = jax.vmap(
+                    lambda p, s: kops.quant4_unpack(p, s, flat.shape[1],
+                                                    cfg.block))(pk, sc)
             return vals.mean(0).reshape(d.shape[1:]).astype(d.dtype), q
 
         r = q.shape[-1]

@@ -60,13 +60,11 @@ def fused_outer_step(delta, error, q_prev, rank_scalar=None,
     under REPRO_USE_PALLAS=1, the unfused jnp op-chain otherwise.  Same
     wire bytes either way; reconstruction agrees within the reorder-ulp
     bound gated in tests/test_kernels.py."""
-    from repro.obs import profile as _prof
-    if _use_pallas():
-        from repro.kernels.fused_compress import fused_compress_ef
-        with _prof.scope("fused_outer_step/pallas"):
+    with jax.named_scope("outer.compress"):
+        if _use_pallas():
+            from repro.kernels.fused_compress import fused_compress_ef
             return fused_compress_ef(delta, error, q_prev, rank_scalar,
                                      block=block)
-    with _prof.scope("fused_outer_step/ref"):
         return ref.outer_step_ref(delta, error, q_prev, rank_scalar, block)
 
 

@@ -126,6 +126,7 @@ def run(args, cfg) -> dict:
     from repro.models import model as M
     from repro.obs import (MetricsRegistry, Tracer, configure_logging,
                            get_logger)
+    from repro.obs import profile as prof
     from repro.parallel import sharding as sh
 
     configure_logging(stream=sys.stdout,
@@ -153,11 +154,13 @@ def run(args, cfg) -> dict:
                                 (max(args.batch, args.requests or 0),
                                  args.prompt_len), 0, cfg.vocab_size)
 
-    if args.paged:
-        st = _run_paged(args, cfg, params, np.asarray(prompt), eos, log,
-                        span, metrics)
-    else:
-        st = _run_dense(args, cfg, params, prompt, eos, log, span, metrics)
+    with prof.capture("serve"):
+        if args.paged:
+            st = _run_paged(args, cfg, params, np.asarray(prompt), eos, log,
+                            span, metrics)
+        else:
+            st = _run_dense(args, cfg, params, prompt, eos, log, span,
+                            metrics)
 
     if tracer is not None:
         tracer.write(args.trace)

@@ -46,7 +46,8 @@ def make_train_step(cfg: ModelConfig, *, inner_lr: float = 1e-4,
     def one_cluster(params, opt, batch):
         (loss, _), grads = jax.value_and_grad(
             lambda p: M.loss_fn(p, cfg, batch), has_aux=True)(params)
-        params, opt = adamw.update(grads, opt, params, lr=inner_lr)
+        with jax.named_scope("train.adamw"):
+            params, opt = adamw.update(grads, opt, params, lr=inner_lr)
         return params, opt, loss
 
     if not per_cluster_h:
@@ -101,12 +102,12 @@ def make_outer_step(cfg: ModelConfig, ccfg: mc.MeshCompressionConfig, *,
                     outer_lr: float = 0.7, outer_momentum: float = 0.9):
     """(params_stacked_postH, outer_state, rank_scalar) ->
     (params_stacked_next, outer_state'). Implements Alg. 2's communicate +
-    delayed outer update with the one-step-delay schedule."""
+    delayed outer update with the one-step-delay schedule.  Regions:
+    ``outer.compress`` (``compress_gather_mean``), ``outer.update`` (the
+    rest)."""
 
-    def outer_step(params_stacked, st: OuterState, rank_scalar):
-        # communicate: compress + gather + mean LAST round's pseudo-grads
-        Delta, q_new = mc.compress_gather_mean(
-            st.delta_pending, st.q_state, rank_scalar, ccfg)
+    @jax.named_scope("outer.update")
+    def _update(params_stacked, st: OuterState, Delta, q_new):
         # Alg. 2 error feedback: e = delta^{t-1} - Delta^{t-1}
         err = jax.tree.map(lambda d, D: d - D[None].astype(d.dtype),
                            st.delta_pending, Delta)
@@ -127,6 +128,12 @@ def make_outer_step(cfg: ModelConfig, ccfg: mc.MeshCompressionConfig, *,
         return params_stacked_new, OuterState(
             anchor=params_new, outer_opt=outer_opt,
             delta_pending=delta_new, error=err, q_state=q_new)
+
+    def outer_step(params_stacked, st: OuterState, rank_scalar):
+        # communicate: compress + gather + mean LAST round's pseudo-grads
+        Delta, q_new = mc.compress_gather_mean(
+            st.delta_pending, st.q_state, rank_scalar, ccfg)
+        return _update(params_stacked, st, Delta, q_new)
 
     return outer_step
 

@@ -63,16 +63,17 @@ def apply_ffn_unit(p, x, cfg: ModelConfig, *, use_moe: bool = False):
     by the train/decode units here and the paged serve engine
     (repro.serve.engine), which must stay bitwise-identical to this path.
     Returns (ffn_out, aux_scalar)."""
-    if use_moe:
-        h = apply_norm(p["ln2"], x, cfg.norm)
-        return moe_lib.apply_moe(p["moe"], h, cfg)
-    if "mlp" not in p:
-        return jnp.zeros_like(x), 0.0
-    h = apply_norm(p["ln2"], x, cfg.norm) if "ln2" in p else x
-    if "w_gate" in p["mlp"]:
-        return apply_swiglu(p["mlp"], h), 0.0
-    from repro.models.layers import apply_gelu_mlp
-    return apply_gelu_mlp(p["mlp"], h), 0.0
+    with jax.named_scope("model.ffn"):
+        if use_moe:
+            h = apply_norm(p["ln2"], x, cfg.norm)
+            return moe_lib.apply_moe(p["moe"], h, cfg)
+        if "mlp" not in p:
+            return jnp.zeros_like(x), 0.0
+        h = apply_norm(p["ln2"], x, cfg.norm) if "ln2" in p else x
+        if "w_gate" in p["mlp"]:
+            return apply_swiglu(p["mlp"], h), 0.0
+        from repro.models.layers import apply_gelu_mlp
+        return apply_gelu_mlp(p["mlp"], h), 0.0
 
 
 def _mk_attn_layer(cfg: ModelConfig, *, window: int, cross: bool = False,
@@ -108,6 +109,7 @@ def _mk_attn_layer(cfg: ModelConfig, *, window: int, cross: bool = False,
                 p["mlp"] = init_swiglu(ks[3], d, cfg.d_ff, dt)
         return p
 
+    @jax.named_scope("model.attn")
     def _self_attn(p, x, ctx):
         h = apply_norm(p["ln1"], x, cfg.norm)
         if is_mla:
@@ -153,23 +155,26 @@ def _mk_attn_layer(cfg: ModelConfig, *, window: int, cross: bool = False,
                           "v": jnp.zeros((batch, ctx_enc_len(cfg), cfg.n_kv_heads, hd), dtype)}
         return c
 
-    def decode_unit(p, x1, cache, index, ctx):
+    @jax.named_scope("model.attn")
+    def _decode_attn(p, x1, cache, index, ctx):
         h = apply_norm(p["ln1"], x1, cfg.norm)
         if is_mla:
-            a, new_self = attn.decode_mla(p["attn"], h, cache["self"], index,
-                                          n_heads=cfg.n_heads, mla=cfg.mla,
-                                          rope_theta=cfg.rope_theta)
-        elif "pos" in cache["self"]:
-            a, new_self = attn.decode_gqa_ring(
+            return attn.decode_mla(p["attn"], h, cache["self"], index,
+                                   n_heads=cfg.n_heads, mla=cfg.mla,
+                                   rope_theta=cfg.rope_theta)
+        if "pos" in cache["self"]:
+            return attn.decode_gqa_ring(
                 p["attn"], h, cache["self"], index, n_heads=cfg.n_heads,
                 n_kv=cfg.n_kv_heads, head_dim=hd, rope_theta=cfg.rope_theta)
-        else:
-            a, new_self = attn.decode_gqa(
-                p["attn"], h, cache["self"], index, n_heads=cfg.n_heads,
-                n_kv=cfg.n_kv_heads, head_dim=hd, rope_theta=cfg.rope_theta,
-                window=window,
-                mrope_positions=ctx.get("mrope_positions"),
-                mrope_sections=cfg.mrope_sections if cfg.mrope else None)
+        return attn.decode_gqa(
+            p["attn"], h, cache["self"], index, n_heads=cfg.n_heads,
+            n_kv=cfg.n_kv_heads, head_dim=hd, rope_theta=cfg.rope_theta,
+            window=window,
+            mrope_positions=ctx.get("mrope_positions"),
+            mrope_sections=cfg.mrope_sections if cfg.mrope else None)
+
+    def decode_unit(p, x1, cache, index, ctx):
+        a, new_self = _decode_attn(p, x1, cache, index, ctx)
         new_cache = dict(cache)
         new_cache["self"] = new_self
         if cfg.parallel_residual and not use_moe:
@@ -398,7 +403,10 @@ def init_params(cfg: ModelConfig, rng) -> Dict[str, Any]:
 # forward
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("model.layers")
 def _run_segments(segs, seg_params, x, ctx, *, remat: bool = True):
+    """The layer stack; its own work (slicing the stacked unit params,
+    residual adds) is region ``model.layers``."""
     aux_total = jnp.zeros((), jnp.float32)
     x = shard_act(x, "act")
     for s, sp in zip(segs, seg_params):
@@ -458,11 +466,13 @@ def make_ctx(cfg, B, S, params=None, x0=None):
     return ctx
 
 
+@jax.named_scope("model.embed")
 def embed_tokens(params, cfg, tokens):
     cd = jnp.dtype(cfg.compute_dtype)
     return params["embed"].astype(cd)[tokens] * (cfg.d_model ** 0.5 if cfg.name.startswith("gemma") else 1.0)
 
 
+@jax.named_scope("model.head")
 def logits_fn(params, cfg, x):
     h = apply_norm(params["final_norm"], x, cfg.norm)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
@@ -502,6 +512,7 @@ def forward(params, cfg, batch, *, remat: bool = True):
     return logits_fn(params, cfg, x), aux
 
 
+@jax.named_scope("model.head")
 def _ce_from_hidden(params, cfg, h_c, tgt_c, mask_c):
     """CE over one sequence chunk: head matmul + vocab-parallel-friendly
     logsumexp/masked-select (no gather over the sharded vocab dim)."""

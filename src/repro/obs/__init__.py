@@ -8,7 +8,8 @@ so the proc ≡ in-process bitwise gates are untouched by tracing.
  - ``obs.trace``   — Chrome-trace-event / Perfetto JSON export of the
    per-round phase spans both sim backends record (modeled on the
    in-process backend, measured wall clock on proc), plus a schema
-   validator and a wall-clock ``Tracer`` for driver code.
+   validator and a wall-clock ``Tracer`` for the launchers (its spans
+   also go into the profiler's host timeline once jax is imported).
  - ``obs.metrics`` — counters/gauges/histograms populated from
    ``RoundEvent`` fields, with a JSONL sink and Prometheus text
    exposition.
@@ -17,8 +18,9 @@ so the proc ≡ in-process bitwise gates are untouched by tracing.
    drift on the proc backend.
  - ``obs.log``     — structured logger replacing ad-hoc ``print()``
    paths (human-readable stream + optional JSON lines).
- - ``obs.profile`` — opt-in ``jax.profiler`` capture hooks
-   (``REPRO_PROFILE=dir``); the only module that touches jax, lazily.
+ - ``obs.profile`` — opt-in ``jax.profiler`` capture
+   (``REPRO_PROFILE=dir``), importing jax lazily.  The jitted steps name
+   their regions with plain ``jax.named_scope``s, always on.
 
 ``import repro.obs`` stays jax-free: the proc backend's timing-only
 workers must keep spawning without a jax import.

@@ -1,17 +1,15 @@
-"""Opt-in ``jax.profiler`` hooks, gated on ``REPRO_PROFILE=dir``.
+"""Opt-in ``jax.profiler`` capture, gated on ``REPRO_PROFILE=dir``.
 
-With the env var unset every helper is a no-op (jax is never imported
+With the env var unset ``capture`` is a no-op (jax is never imported
 from here — this module must stay importable in the jax-free timing-only
-proc workers).  With ``REPRO_PROFILE=/some/dir``:
+proc workers).  With ``REPRO_PROFILE=/some/dir``, ``capture(name)``
+wraps a region in ``jax.profiler.trace``, writing a TensorBoard-loadable
+profile to ``$REPRO_PROFILE/<name>``.
 
- - ``capture(name)`` wraps a region in ``jax.profiler.trace``, writing a
-   TensorBoard-loadable profile to ``$REPRO_PROFILE/<name>``;
- - ``annotate(name)`` wraps a host-side region in
-   ``jax.profiler.TraceAnnotation`` (shows up on the profiler's host
-   timeline);
- - ``scope(name)`` returns ``jax.named_scope`` for *traced* code — the
-   op names land in HLO metadata, so the pp inner engine and the Pallas
-   kernel dispatch are findable in the captured device timeline.
+What the capture shows needs nothing from here: the jitted steps name
+their regions with plain ``jax.named_scope``s (``model.*``, ``train.*``,
+``outer.*``, ``decode.*``), always on, and ``obs.Tracer`` spans enter
+``jax.profiler.TraceAnnotation`` once jax is imported.
 """
 from __future__ import annotations
 
@@ -23,10 +21,6 @@ from typing import Optional
 def profile_dir() -> Optional[str]:
     d = os.environ.get("REPRO_PROFILE", "").strip()
     return d or None
-
-
-def enabled() -> bool:
-    return profile_dir() is not None
 
 
 @contextlib.contextmanager
@@ -41,24 +35,3 @@ def capture(name: str):
     os.makedirs(path, exist_ok=True)
     with jax.profiler.trace(path):
         yield
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Host-timeline annotation around a region (no-op if unset)."""
-    if not enabled():
-        yield
-        return
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def scope(name: str):
-    """``jax.named_scope`` for traced code paths (no-op if unset).
-    Unlike the two region managers this *names ops* rather than timing a
-    host region — use it inside functions that will be jitted."""
-    if not enabled():
-        return contextlib.nullcontext()
-    import jax
-    return jax.named_scope(name)

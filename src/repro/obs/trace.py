@@ -61,7 +61,7 @@ import json
 import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 # pid of the coordinator/global row (clusters use their own id)
@@ -236,7 +236,11 @@ class Tracer:
     """Wall-clock span recorder for driver code (``launch/train.py``):
     ``with tracer.span("outer"): ...`` records a measured complete event.
     Threads map to tids in first-seen order, so concurrent spans land on
-    separate rows and the nesting invariant holds per row."""
+    separate rows and the nesting invariant holds per row.
+
+    Once jax is imported (by the caller: this module never imports it)
+    each span also enters ``jax.profiler.TraceAnnotation``, so a profiler
+    capture shows these spans on the device timeline's clock."""
 
     def __init__(self, process: str = "driver", pid: int = 0):
         self.pid = pid
@@ -253,9 +257,13 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, **args: Any):
+        jax = sys.modules.get("jax")
+        ann = (jax.profiler.TraceAnnotation(name) if jax is not None
+               else nullcontext())
         start = time.monotonic()
         try:
-            yield
+            with ann:
+                yield
         finally:
             end = time.monotonic()
             ev = {"name": name, "cat": "measured", "ph": "X",

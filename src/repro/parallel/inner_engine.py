@@ -185,15 +185,11 @@ def make_pp_train_step(cfg: ModelConfig, mesh: Mesh,
     loss_fn = PP.make_pp_loss(cfg, mesh, pcfg,
                               cluster_stacked=cluster_stacked)
 
-    from repro.obs import profile as _prof
-
     def train_step(params, opt, tokens):
-        # named scope shows up in REPRO_PROFILE captures / XLA HLO names;
-        # a nullcontext when profiling is off (identical trace either way)
-        with _prof.scope("pp_train_step"):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
-            grads = dict(grads)
-            grads["active"] = jnp.zeros_like(grads["active"])
+        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+        grads = dict(grads)
+        grads["active"] = jnp.zeros_like(grads["active"])
+        with jax.named_scope("train.adamw"):
             if cluster_stacked:
                 new_params, opt = jax.vmap(
                     lambda p_, g_, o_: adamw.update(g_, o_, p_,
@@ -202,9 +198,9 @@ def make_pp_train_step(cfg: ModelConfig, mesh: Mesh,
             else:
                 new_params, opt = adamw.update(grads, opt, params,
                                                lr=inner_lr)
-            new_params = dict(new_params)
-            new_params["active"] = params["active"]
-            return new_params, opt, loss
+        new_params = dict(new_params)
+        new_params["active"] = params["active"]
+        return new_params, opt, loss
 
     return train_step
 

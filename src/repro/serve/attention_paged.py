@@ -164,6 +164,7 @@ def pallas_paged_attention(q, cache, page_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, dh), q.dtype),
         interpret=kernels.pallas_interpret(),
+        name="paged_attention",
     )(page_tables, lengths, q.reshape(S, H, dh), cache["k"], cache["v"])
     return out.reshape(S, 1, H, dh)
 

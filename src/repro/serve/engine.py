@@ -86,7 +86,12 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
     """The engine's device step: one token per slot through every layer,
     writing its K/V into the pages.  Returns ``(next_tokens, caches)``,
     and the step's logits ``(max_seqs, vocab)`` last when
-    ``return_logits``."""
+    ``return_logits``.
+
+    Regions: ``decode.layers`` (the layer scan, whose own work is slicing
+    and writing back the stacked weights and KV pool), inside it
+    ``model.attn`` with ``decode.kv_write`` and ``decode.paged_attention``,
+    and ``model.ffn``; ``model.embed`` and ``model.head`` around it."""
     ok, why = supports_paged(cfg)
     if not ok:
         raise NotImplementedError(why)
@@ -96,17 +101,20 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
     def unit_step(p, x1, cache, lengths, active, page_tables, *,
                   window: int, use_moe: bool):
         # mirrors model decode_unit / attention.decode_gqa op-for-op
-        h = apply_norm(p["ln1"], x1, cfg.norm)
-        q, k_new, v_new = attn._qkv(p["attn"], h, cfg.n_heads,
-                                    cfg.n_kv_heads, hd)
-        pos = lengths[:, None]
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k_new = apply_rope(k_new, pos, cfg.rope_theta)
-        cache = pa.write_kv(cache, k_new[:, 0], v_new[:, 0], page_tables,
-                            lengths, active)
-        o = pa.paged_attention(q, cache, page_tables, lengths,
-                               window=window, backend=backend)
-        a = o.reshape(x1.shape[0], 1, -1) @ p["attn"]["wo"]
+        with jax.named_scope("model.attn"):
+            h = apply_norm(p["ln1"], x1, cfg.norm)
+            q, k_new, v_new = attn._qkv(p["attn"], h, cfg.n_heads,
+                                        cfg.n_kv_heads, hd)
+            pos = lengths[:, None]
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k_new = apply_rope(k_new, pos, cfg.rope_theta)
+            with jax.named_scope("decode.kv_write"):
+                cache = pa.write_kv(cache, k_new[:, 0], v_new[:, 0],
+                                    page_tables, lengths, active)
+            with jax.named_scope("decode.paged_attention"):
+                o = pa.paged_attention(q, cache, page_tables, lengths,
+                                       window=window, backend=backend)
+            a = o.reshape(x1.shape[0], 1, -1) @ p["attn"]["wo"]
         if cfg.parallel_residual and not use_moe:
             f, _ = M.apply_ffn_unit(p, x1, cfg, use_moe=use_moe)
             x1 = x1 + a + f
@@ -130,10 +138,12 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
                                   window=_w, use_moe=_m)
                 return x1, c
 
-            x1, nc = jax.lax.scan(scan_fn, x1, (sp, cache))
+            with jax.named_scope("decode.layers"):
+                x1, nc = jax.lax.scan(scan_fn, x1, (sp, cache))
             new_caches.append(nc)
-        logits = M.logits_fn(params, cfg, x1)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("model.head"):
+            logits = M.logits_fn(params, cfg, x1)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         if return_logits:
             return nxt, new_caches, logits[:, -1]
         return nxt, new_caches
@@ -228,24 +238,34 @@ class ServeEngine:
     # -- stepping ----------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration (admit -> plan -> device step -> commit).
-        Returns False when there is nothing left to do."""
+        Returns False when there is nothing left to do.
+
+        Spans (through ``span``): ``admit``, ``plan``, ``device_step``
+        holding ``put`` (the inputs' copies to the device), ``dispatch``
+        (the jitted call) and ``fetch`` (the next tokens back to the
+        host), then ``commit``."""
         sched = self.sched
         if not sched.has_work():
             return False
         with self._span("admit"):
             sched.admit_ready(self.step_count, time.monotonic())
-        plan = sched.plan_step()
+        with self._span("plan"):
+            plan = sched.plan_step()
         if plan is None:
             # every remaining request arrives in the future: tick the clock
             self.step_count += 1
             return True
         tokens, lengths, active = plan
         with self._span("device_step", n_active=int(active.sum())):
-            nxt, self.caches, *logits = self._fn(
-                self.params, self.caches, jnp.asarray(tokens),
-                jnp.asarray(lengths), jnp.asarray(active),
-                jnp.asarray(self.pages.page_table))
-            nxt = np.asarray(nxt)
+            with self._span("put"):
+                args = (jnp.asarray(tokens), jnp.asarray(lengths),
+                        jnp.asarray(active),
+                        jnp.asarray(self.pages.page_table))
+            with self._span("dispatch"):
+                nxt, self.caches, *logits = self._fn(
+                    self.params, self.caches, *args)
+            with self._span("fetch"):
+                nxt = np.asarray(nxt)
         if self.logits is not None:
             rows = np.asarray(logits[0])
             for i in np.flatnonzero(active):
