@@ -81,6 +81,14 @@ def dense_kv_bytes(cfg: ModelConfig, *, n_seqs: int, s_max: int,
             * cfg.resolved_head_dim * 2 * dt.itemsize)
 
 
+def segment_windows(cfg: ModelConfig) -> List[Tuple[int, int]]:
+    """``(units, attention window)`` of each segment: local segments
+    attend over ``cfg.sliding_window`` positions, the others (0) over all
+    cached ones."""
+    return [(s.n, cfg.sliding_window if s.kind == "local" else 0)
+            for s in M.build_segments(cfg)]
+
+
 def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
                            return_logits: bool = False):
     """The engine's device step: one token per slot through every layer,
@@ -97,6 +105,7 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
         raise NotImplementedError(why)
     hd = cfg.resolved_head_dim
     segs = M.build_segments(cfg)
+    windows = [w for _, w in segment_windows(cfg)]
 
     def unit_step(p, x1, cache, lengths, active, page_tables, *,
                   window: int, use_moe: bool):
@@ -128,8 +137,8 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
         x1 = M.embed_tokens(params, cfg, tokens[:, None])
         x1 = M.shard_act(x1, "act")
         new_caches = []
-        for s, sp, cache in zip(segs, params["segments"], caches):
-            window = cfg.sliding_window if s.kind == "local" else 0
+        for s, window, sp, cache in zip(segs, windows, params["segments"],
+                                        caches):
             use_moe = s.kind == "moe"
 
             def scan_fn(x1, pc, _w=window, _m=use_moe):
@@ -199,6 +208,9 @@ class ServeEngine:
         self.step_count = 0
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        self._windows = segment_windows(cfg)
+        self.kv_pages_read = 0
+        self.kv_pages_grid = 0
         self._metrics = metrics
         self._span = (span if span is not None
                       else (lambda name, **kw: contextlib.nullcontext()))
@@ -276,6 +288,16 @@ class ServeEngine:
         n_active = int(active.sum())
         self.prefill_tokens += n_prefill
         self.decode_tokens += n_active - n_prefill
+        # the paged kernel's copies this step, over every layer: the
+        # pages each active slot's length (and window) reaches, against
+        # the whole table rows a fixed grid would walk
+        pages_read = sum(
+            n * int(pa.page_span(lengths[active], self.page_size, w)[1].sum())
+            for n, w in self._windows)
+        pages_grid = (sum(n for n, _ in self._windows) * n_active
+                      * self.pages.max_pages_per_seq)
+        self.kv_pages_read += pages_read
+        self.kv_pages_grid += pages_grid
         with self._span("commit"):
             sched.commit(nxt, self.step_count, time.monotonic())
         if self._metrics is not None:
@@ -283,6 +305,8 @@ class ServeEngine:
             m.counter("repro_serve_steps").inc()
             m.counter("repro_serve_prefill_tokens").inc(n_prefill)
             m.counter("repro_serve_decode_tokens").inc(n_active - n_prefill)
+            m.counter("repro_serve_kv_pages_read").inc(pages_read)
+            m.counter("repro_serve_kv_pages_grid").inc(pages_grid)
             m.gauge("repro_serve_pages_in_use").set(self.pages.used_pages)
             m.gauge("repro_serve_waiting").set(len(sched.waiting))
         self.step_count += 1
@@ -319,6 +343,8 @@ class ServeEngine:
             # deterministic throughput: both policies run the identical
             # compiled step, so tokens-per-step ratios ARE tokens/s ratios
             "decode_tok_per_step": self.decode_tokens / steps,
+            "kv_pages_read": self.kv_pages_read,
+            "kv_pages_grid": self.kv_pages_grid,
             "ttft_steps_p50": _pct(ttft_steps, 50),
             "ttft_steps_p99": _pct(ttft_steps, 99),
             "ttft_ms_p50": _pct(ttft_ms, 50),

@@ -311,19 +311,28 @@ def test_fused_ops_dispatch(monkeypatch):
 # paged decode attention (serve engine): pallas kernel vs ref gather path
 # ---------------------------------------------------------------------------
 
-def _paged_fixture(seed, *, S, P, ps, KV, G, dh, fill_frac=0.8):
+def _paged_fixture(seed, *, S, P, ps, KV, G, dh, fill_frac=0.8,
+                   lengths=None):
     """Random page pool + table + lengths; scratch page 0 holds garbage to
-    prove the masking contract kills unallocated reads."""
+    prove the masking contract kills unallocated reads.  ``lengths``, when
+    given, fixes each slot's length; ``None`` in it is an inactive slot
+    (length 0, all-zero table row), and the pool then holds just the
+    pages the slots use."""
     from repro.serve.pages import PageManager
 
     rng = np.random.default_rng(seed)
-    n_pages = S * P
+    n_pages = S * P if lengths is None else sum(
+        n // ps + 1 for n in lengths if n is not None)
     pm = PageManager(n_pages, ps, S, P)
+    given = lengths
     lengths = np.zeros(S, np.int32)
     for s in range(S):
-        lengths[s] = rng.integers(1, int(P * ps * fill_frac) + 1)
-        pm.admit(s, int(lengths[s]))
-        for pos in range(int(lengths[s])):
+        if given is not None and given[s] is None:
+            continue
+        lengths[s] = (given[s] if given is not None
+                      else rng.integers(1, int(P * ps * fill_frac) + 1))
+        pm.admit(s, int(lengths[s]) + 1)
+        for pos in range(int(lengths[s]) + 1):
             pm.ensure(s, pos)
     H = KV * G
     k = rng.normal(size=(1 + n_pages, ps, KV, dh)).astype(np.float32)
@@ -336,16 +345,24 @@ def _paged_fixture(seed, *, S, P, ps, KV, G, dh, fill_frac=0.8):
             jnp.asarray(lengths))
 
 
-@pytest.mark.parametrize("window", [0, 5])
-@pytest.mark.parametrize("S,P,ps,KV,G,dh", [
-    (3, 4, 4, 2, 2, 8),
-    (2, 3, 8, 1, 4, 16),
+@pytest.mark.parametrize("window", [0, 5, 70])
+@pytest.mark.parametrize("S,P,ps,KV,G,dh,lengths", [
+    pytest.param(3, 4, 4, 2, 2, 8, None, id="3-4-4-2-2-8"),
+    pytest.param(2, 3, 8, 1, 4, 16, None, id="2-3-8-1-4-16"),
+    # blocks of 4 pages of 16 over a 6-page row: lengths 0, ps - 1, ps,
+    # the last position of the first block and the first of the second,
+    # a full slot, an inactive slot; window 70 spans two blocks
+    pytest.param(7, 6, 16, 2, 2, 8, (0, 15, 16, 63, 64, 95, None),
+                 id="block-edges"),
+    # MHA at many heads (the G = 1 form), 8-page blocks over 11 pages
+    pytest.param(3, 11, 8, 8, 1, 64, (87, 64, 7), id="mha-8-heads"),
 ])
-def test_paged_attention_pallas_matches_ref(window, S, P, ps, KV, G, dh):
+def test_paged_attention_pallas_matches_ref(window, S, P, ps, KV, G, dh,
+                                            lengths):
     from repro.serve import attention_paged as ap
 
     q, cache, table, lengths = _paged_fixture(0, S=S, P=P, ps=ps, KV=KV,
-                                              G=G, dh=dh)
+                                              G=G, dh=dh, lengths=lengths)
     ref_out = ap.ref_paged_attention(q, cache, table, lengths,
                                      window=window)
     pal_out = ap.pallas_paged_attention(q, cache, table, lengths,

@@ -335,3 +335,57 @@ def test_paged_engine_eos_and_backpressure_integration():
         if r.finish_reason == "eos":
             assert r.generated[-1] == cfg.eos_id
             assert cfg.eos_id not in r.generated[:-1]
+
+
+def test_paged_engine_counts_the_kv_pages_its_kernel_reads():
+    """``repro_serve_kv_pages_read`` / ``_grid`` (and ``stats()``): each
+    step, over every layer, the pages each active slot's length reaches
+    (window-bounded on local layers), against the active slots' whole
+    table rows, recomputed from the lengths the scheduler planned."""
+    import jax
+
+    from repro.configs.base import get_config
+    from repro.models import model as M
+    from repro.obs import MetricsRegistry
+    from repro.serve.engine import ServeEngine, segment_windows
+
+    cfg = get_config("gemma3-1b").reduced()       # window-8 and global layers
+    windows = segment_windows(cfg)
+    assert sorted(w for _, w in windows) == [0, 8]
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    reg = MetricsRegistry()
+    ps, max_pages = 4, 6
+    eng = ServeEngine(params, cfg, max_seqs=3, page_size=ps, n_pages=12,
+                      max_pages_per_seq=max_pages, eos_id=None, metrics=reg)
+    plans = []
+    plan_step = eng.sched.plan_step
+
+    def recording_plan_step():
+        plan = plan_step()
+        if plan is not None:
+            plans.append((plan[1].copy(), plan[2].copy()))
+        return plan
+
+    eng.sched.plan_step = recording_plan_step
+    rng = np.random.default_rng(0)
+    for n_prompt, n_new, arrival in ((3, 14, 0), (9, 5, 0), (2, 3, 4),
+                                     (5, 6, 1)):
+        eng.submit(rng.integers(0, cfg.vocab_size, n_prompt).tolist(), n_new,
+                   arrival=arrival)
+    st = eng.run()
+
+    def pages(length, window):
+        lo = max(0, length - window + 1) if window else 0
+        return length // ps - lo // ps + 1
+
+    read = sum(n * pages(int(length), w) for lengths, active in plans
+               for length in lengths[active] for n, w in windows)
+    grid = sum(sum(n for n, _ in windows) * int(active.sum()) * max_pages
+               for _, active in plans)
+    # the window drops whole pages in some step
+    assert any(pages(int(length), 8) < pages(int(length), 0)
+               for lengths, active in plans for length in lengths[active])
+    assert reg.counter("repro_serve_kv_pages_read").value == read
+    assert reg.counter("repro_serve_kv_pages_grid").value == grid
+    assert (st["kv_pages_read"], st["kv_pages_grid"]) == (read, grid)
+    assert 0 < read < grid

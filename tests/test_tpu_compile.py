@@ -87,6 +87,13 @@ def _kernel_cases(sds):
             sds((S, 1, H, dh)), sds((1 + S * pages, ps, H, dh)),
             sds((1 + S * pages, ps, H, dh)), sds((S, pages), i32),
             sds((S,), i32)),
+        # serve-chat's shape: 32 slots of 96 pages of 8 over a 768-page
+        # pool, OPT-1.3B's 32 MHA heads
+        "paged_attention_serve_chat": (
+            lambda q, k, v, t, n: pallas_paged_attention(
+                q, {"k": k, "v": v}, t, n),
+            sds((32, 1, H, dh)), sds((1 + 768, ps, H, dh)),
+            sds((1 + 768, ps, H, dh)), sds((32, 96), i32), sds((32,), i32)),
         "flash_attention": (flash_attention_pallas,
                             *[sds((1, 2048, H, dh))] * 3),
         "quant4_pack": (quant4_pack_pallas, sds((2048 * 64,))),
@@ -108,8 +115,8 @@ def _kernel_cases(sds):
     return cases
 
 
-KERNELS = ["paged_attention", "flash_attention", "quant4_pack",
-           "quant4_unpack", "lowrank_mm",
+KERNELS = ["paged_attention", "paged_attention_serve_chat", "flash_attention",
+           "quant4_pack", "quant4_unpack", "lowrank_mm",
            "fused_compress_ef_2048x8192_r64", "fused_decompress_2048x8192_r64",
            "fused_compress_ef_2048x6144_r512",
            "fused_decompress_2048x6144_r512"]
