@@ -1,19 +1,23 @@
-"""Wire-honest mesh compression for the outer step.
+"""Mesh compression for the outer step.
 
 The single-host simulator (core.compression) round-trips values; here the
-compiled HLO itself must carry only *compressed* bytes across the cluster
-axis, so the roofline parser reads honest numbers. Per 2-D parameter matrix
-(per scan unit, per cluster):
+outer step runs on the cluster-stacked state of a (clusters, data, model)
+mesh. Per 2-D parameter matrix (per scan unit, per cluster):
 
     P = M Q_warm ; P <- CholeskyQR(P) ; Q = M^T P          (PowerSGD step)
     payload = (pack_int4(P), scales_P, pack_int4(Q), scales_Q)
-    Delta   = mean_over_clusters( unpack(payload) )        <- the only op
-                                                              crossing the
-                                                              slow axis
+    Delta   = mean_over_clusters( unpack(payload) )
 
-The mean over the cluster-stacked payload forces GSPMD to move the uint8
-payload (or at worst the same bytes in f32 — verified in the dry-run HLO by
-the collective parser). 1-D/small leaves are quantized without low-rank.
+What crosses the cluster axis is what GSPMD makes of that mean, and it is
+not the payload.  Compiled for a TPU v5e 2 x 2 mesh (2 clusters x 2 model
+ranks, Phi-3-medium widths), the u8 payload's all-gathers stay inside each
+cluster; the clusters then all-reduce the *reconstructed* f32 matrices:
+the unpacked P Q^T products with the cluster sum folded into their
+contraction, and one combined (tuple-shaped) all-reduce for the rest.
+That is ~465x the int4 payload (``wire_bytes_tree``).  The region
+``outer.exchange`` holds the unpacking and the mean, and
+``launch/dryrun.parse_collective_bytes`` counts what crosses.
+1-D/small leaves are quantized without low-rank.
 """
 from __future__ import annotations
 
@@ -97,10 +101,10 @@ def _decompress_leaf_matrix(payload, m, n, r, cfg: MeshCompressionConfig):
 def compress_gather_mean(delta_stacked, q_state, rank_scalar,
                          cfg: MeshCompressionConfig):
     """delta_stacked: cluster-stacked pytree (C, ...). Returns
-    (Delta mean tree (...), new q_state). The cross-cluster data movement is
-    the packed payload (uint8 + scales).  Region ``outer.compress``, with
-    the Cholesky-QR in ``outer.orthonormalize`` and int4 pack/unpack in
-    ``outer.quant``."""
+    (Delta mean tree (...), new q_state).  Region ``outer.compress``, with
+    the Cholesky-QR in ``outer.orthonormalize``, int4 pack/unpack in
+    ``outer.quant``, and the unpacking and the mean over clusters, where
+    the compiled step crosses clusters, in ``outer.exchange``."""
 
     def one(path, d, q):
         C = d.shape[0]
@@ -111,10 +115,13 @@ def compress_gather_mean(delta_stacked, q_state, rank_scalar,
             with jax.named_scope("outer.quant"):
                 pk, sc = jax.vmap(
                     lambda v: kops.quant4_pack(v, cfg.block))(flat)
-                vals = jax.vmap(
-                    lambda p, s: kops.quant4_unpack(p, s, flat.shape[1],
-                                                    cfg.block))(pk, sc)
-            return vals.mean(0).reshape(d.shape[1:]).astype(d.dtype), q
+            with jax.named_scope("outer.exchange"):
+                with jax.named_scope("outer.quant"):
+                    vals = jax.vmap(
+                        lambda p, s: kops.quant4_unpack(
+                            p, s, flat.shape[1], cfg.block))(pk, sc)
+                mean = vals.mean(0)
+            return mean.reshape(d.shape[1:]).astype(d.dtype), q
 
         r = q.shape[-1]
         dm = d.reshape(C * lead, m, n).astype(jnp.float32)
@@ -122,9 +129,10 @@ def compress_gather_mean(delta_stacked, q_state, rank_scalar,
         comp = jax.vmap(
             lambda M, qp: _compress_leaf_matrix(M, qp, rank_scalar, cfg))
         payload, q_new = comp(dm, qm)
-        dec = jax.vmap(
-            lambda pl: _decompress_leaf_matrix(pl, m, n, r, cfg))(payload)
-        Delta = dec.reshape(C, lead, m, n).mean(0).reshape(d.shape[1:])
+        with jax.named_scope("outer.exchange"):
+            dec = jax.vmap(
+                lambda pl: _decompress_leaf_matrix(pl, m, n, r, cfg))(payload)
+            Delta = dec.reshape(C, lead, m, n).mean(0).reshape(d.shape[1:])
         return Delta.astype(d.dtype), q_new.reshape(q.shape)
 
     flat_d, treedef = jax.tree_util.tree_flatten_with_path(delta_stacked)

@@ -1,6 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
-                           " --xla_force_host_platform_device_count=512")
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
+                               " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run (spec deliverable e).
 
 Lowers + compiles every runnable (architecture x input-shape) combination on
@@ -12,8 +13,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch granite-3-8b \
       --shape train_4k [--multi-pod] [--all] [--out results.json]
 
-The XLA_FLAGS line above MUST stay the first statement: jax locks the
-device count on first init, and only the dry-run wants 512 host devices.
+The XLA_FLAGS lines above MUST stay the first statements: jax locks the
+device count on first init, and only the dry-run's own process wants 512
+host devices (an import, e.g. for ``parse_collective_bytes``, leaves them).
 """
 import argparse
 import dataclasses
@@ -44,10 +46,37 @@ HBM_BW = 819e9               # bytes/s / chip
 ICI_BW = 50e9                # bytes/s/link (intra-pod)
 DCN_BW = 0.125e9             # 1 Gbps decentralized link (paper's scenario)
 
+# a collective's result: one array, or a tuple of them where the compiler
+# combined several collectives into one (layouts such as ``T(8,128)`` hold
+# parentheses, so a tuple ends at the ``)`` before the opcode)
 COLLECTIVE_RE = re.compile(
-    r"= (f8|f16|f32|f64|bf16|u8|s8|u32|s32|pred)\[([\d,]*)\]\S*\s+"
+    r"= (\(.*?\)|\S+) "
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-done)?\(([^\n]*)")
+    r"(-start|-done)?\(([^\n]*)")
+ARRAY_RE = re.compile(r"\b([a-z]\w*)\[([\d,]*)\]")
+
+
+def _tuple_items(shape: str):
+    """The top-level items of a tuple shape ``(a, (b, c), d)``."""
+    items, depth, start = [], 0, 1
+    for i, ch in enumerate(shape):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if (ch == "," and depth == 1) or (ch == ")" and depth == 0):
+            items.append(shape[start:i].strip())
+            start = i + 1
+    return items
+
+
+def _result_shape(shape: str, kind: str, phase: str) -> str:
+    """The arrays a collective delivers.  An async all-reduce's start is
+    shaped as its result; an all-gather's or a permute's start holds its
+    operands first, then its results (and a permute's context words)."""
+    if phase == "-start" and kind != "all-reduce":
+        return _tuple_items(shape)[1]
+    return shape
 
 GROUPS_LITERAL_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
 GROUPS_IOTA_RE = re.compile(
@@ -96,23 +125,26 @@ DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8": 1,
 
 def parse_collective_bytes(hlo_text: str,
                            cluster_size: int = 0) -> Dict[str, Any]:
-    """Sum output-operand sizes of collective ops in the (post-SPMD) HLO.
+    """Sum the result sizes of the collective ops in the (post-SPMD) HLO,
+    every array of a combined (tuple-shaped) collective included.  An
+    async pair counts once, at its ``-start``, by its results alone.
     When cluster_size > 0, traffic whose replica groups span clusters is
     reported separately (that is the 1 Gbps decentralized boundary)."""
     out: Dict[str, Any] = {}
     cross = 0
     cross_by_dtype: Dict[str, int] = {}
     for m in COLLECTIVE_RE.finditer(hlo_text):
-        dt, dims, kind, attrs = m.group(1), m.group(2), m.group(3), m.group(4)
-        n = 1
-        if dims.strip():
-            for d in dims.split(","):
-                n *= int(d)
-        nbytes = n * DTYPE_BYTES.get(dt, 4)
-        out[kind] = out.get(kind, 0) + nbytes
-        if cluster_size:
-            grp = _first_group(attrs)
-            if _crosses_cluster(grp, cluster_size):
+        shape, kind, phase, attrs = m.groups()
+        if phase == "-done":
+            continue
+        by_dtype: Dict[str, int] = {}
+        for dt, dims in ARRAY_RE.findall(_result_shape(shape, kind, phase)):
+            n = math.prod(int(d) for d in dims.split(",") if d)
+            by_dtype[dt] = by_dtype.get(dt, 0) + n * DTYPE_BYTES.get(dt, 4)
+        out[kind] = out.get(kind, 0) + sum(by_dtype.values())
+        if cluster_size and _crosses_cluster(_first_group(attrs),
+                                             cluster_size):
+            for dt, nbytes in by_dtype.items():
                 cross += nbytes
                 cross_by_dtype[dt] = cross_by_dtype.get(dt, 0) + nbytes
     if cluster_size:

@@ -11,7 +11,8 @@ TABLE = {
     "deepseek-v2-236b": (60, 5120, 128, 128, None, 102400),
     "arctic-480b": (35, 7168, 56, 8, 4864, 32000),
     "stablelm-12b": (40, 5120, 32, 8, 13824, 100352),
-    "phi3-medium-14b": (40, 5120, 40, 10, 17920, 100352),
+    # vocab from the published config.json (microsoft/Phi-3-medium-4k-instruct)
+    "phi3-medium-14b": (40, 5120, 40, 10, 17920, 32064),
     "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32000),
     "seamless-m4t-large-v2": (24, 1024, 16, 16, 8192, 256206),
     "qwen2-vl-7b": (28, 3584, 28, 4, 18944, 152064),

@@ -31,9 +31,16 @@ def cfg():
 
 
 def _locations(lowered):
-    """Every op path the compiled program carries."""
-    return set(re.findall(r'op_name="([^"]*)"',
-                          lowered.compile().as_text()))
+    """Every op path the compiled program carries.  The persistent
+    compilation cache's key leaves out op metadata, so a cached program
+    may carry another tree's: compile afresh."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    return set(re.findall(r'op_name="([^"]*)"', text))
 
 
 def _regions(path):
@@ -81,12 +88,17 @@ def test_outer_step_names_the_compressor_and_the_update(cfg):
         p, ost, jax.ShapeDtypeStruct((), jnp.int32)))
     for r in ("outer.compress", "outer.update"):
         assert _paths_with(locs, r), r
-    # the Cholesky-QR and the int4 packing inside the compressor
-    for r in ("outer.orthonormalize", "outer.quant"):
+    # the Cholesky-QR, the int4 packing, and the unpacking and mean over
+    # clusters (where the compiled step crosses them) inside the compressor
+    for r in ("outer.orthonormalize", "outer.quant", "outer.exchange"):
         assert _paths_with(locs, r)
         assert all("outer.compress" in _regions(p)
                    for p in _paths_with(locs, r))
     assert not _paths_with(locs, "outer.compress", "outer.update")
+    # the exchange holds the low-rank factors' product and the unpacking of
+    # both kinds of leaf
+    assert any("dot_general" in p for p in _paths_with(locs, "outer.exchange"))
+    assert _paths_with(locs, "outer.exchange", "outer.quant")
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
