@@ -155,17 +155,20 @@ def check_kernels(checks: Checks) -> None:
     highest = lambda: jax.default_matmul_precision("highest")
 
     # paged decode attention at OPT-1.3B's 32 x 64 heads: 8 sequences of up
-    # to 20 pages of 8 tokens, pages scattered over the pool
+    # to 20 pages of 8 tokens, pages scattered over a lane-dense pool of
+    # 2 units, read at unit 1
     S, P, ps, H, dh = 8, 20, 8, 32, 64
     rng = np.random.default_rng(0)
     table = jnp.asarray(1 + rng.permutation(S * P).reshape(S, P), jnp.int32)
     lengths = jnp.asarray(rng.integers(0, P * ps, S), jnp.int32)
-    cache = {"k": normal(1, (1 + S * P, ps, H, dh)),
-             "v": normal(2, (1 + S * P, ps, H, dh))}
+    cache = {"k": normal(1, (2, 1 + S * P, ps, H * dh)),
+             "v": normal(2, (2, 1 + S * P, ps, H * dh))}
     q = normal(3, (S, 1, H, dh))
-    out = jax.jit(ap.pallas_paged_attention)(q, cache, table, lengths)
+    layer = jnp.int32(1)
+    out = jax.jit(ap.pallas_paged_attention)(q, cache, layer, table, lengths)
     with highest():
-        want = jax.jit(ap.ref_paged_attention)(q, cache, table, lengths)
+        want = jax.jit(ap.ref_paged_attention)(q, cache, layer, table,
+                                               lengths)
     checks.bound("paged_attention", _tol_ratio(out, want, *PAGED_TOL), 1.0,
                  "(|err|/(atol+rtol|ref|)) ")
 

@@ -3,7 +3,12 @@
 One jit-compiled, shape-stable decode step serves every phase and
 occupancy: ``(params, caches, tokens(S,), lengths(S,), active(S,),
 page_tables(S,P)) -> (next_tokens(S,), caches')`` with the cache buffers
-donated (the page pool is updated in place, never copied per step).
+donated.  The pool is lane-dense and the layer loop carries it: each layer
+writes its S new rows in place and the paged kernel reads the layer's
+pages from the pool as stored, so a step moves the rows it writes and the
+pages it reads, not the pool (compiled for a v5e at serve-chat's size:
+the step aliases exactly the pool's bytes and allocates no pool-sized
+temporary; ``tests/test_tpu_compile.py``).
 Prefill is by decode — the scheduler feeds prompt tokens one per step —
 so there is exactly one executable, compiled once.
 
@@ -51,12 +56,12 @@ def supports_paged(cfg: ModelConfig) -> Tuple[bool, str]:
 
 def init_kv_pages(cfg: ModelConfig, *, n_pages: int, page_size: int,
                   dtype=None) -> List[Dict[str, jnp.ndarray]]:
-    """Per-segment paged KV stores ``(n_units, 1 + n_pages, ps, KV, dh)``.
+    """Per-segment paged KV stores ``(n_units, 1 + n_pages, ps, KV * dh)``,
+    lane-dense: a page row holds the keys (or values) of every KV head.
     Index 0 along the page dim is the scratch page (PageManager contract);
     one physical page id addresses the same slot in every unit's store."""
     dt = jnp.dtype(dtype or cfg.param_dtype)
-    hd = cfg.resolved_head_dim
-    shape = (n_pages + 1, page_size, cfg.n_kv_heads, hd)
+    shape = (n_pages + 1, page_size, cfg.n_kv_heads * cfg.resolved_head_dim)
     return [{"k": jnp.zeros((s.n,) + shape, dt),
              "v": jnp.zeros((s.n,) + shape, dt)}
             for s in M.build_segments(cfg)]
@@ -64,9 +69,11 @@ def init_kv_pages(cfg: ModelConfig, *, n_pages: int, page_size: int,
 
 def kv_pool_bytes(cfg: ModelConfig, *, n_pages: int, page_size: int,
                   dtype=None) -> int:
+    """Bytes of the K and V stores ``init_kv_pages`` makes for ``n_pages``
+    pages: the pages and the scratch page, with no padding."""
     dt = jnp.dtype(dtype or cfg.param_dtype)
     n_units = sum(s.n for s in M.build_segments(cfg))
-    return (n_units * n_pages * page_size * cfg.n_kv_heads
+    return (n_units * (1 + n_pages) * page_size * cfg.n_kv_heads
             * cfg.resolved_head_dim * 2 * dt.itemsize)
 
 
@@ -96,10 +103,15 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
     and the step's logits ``(max_seqs, vocab)`` last when
     ``return_logits``.
 
+    The layer scan carries each segment's pool with the activations and
+    takes each layer's weights with its index: ``write_kv`` updates the
+    carried pool at that layer and the kernel reads it there, so the
+    donated pool is the loop's buffer from the first layer to the output.
+
     Regions: ``decode.layers`` (the layer scan, whose own work is slicing
-    and writing back the stacked weights and KV pool), inside it
-    ``model.attn`` with ``decode.kv_write`` and ``decode.paged_attention``,
-    and ``model.ffn``; ``model.embed`` and ``model.head`` around it."""
+    the stacked weights), inside it ``model.attn`` with
+    ``decode.kv_write`` and ``decode.paged_attention``, and ``model.ffn``;
+    ``model.embed`` and ``model.head`` around it."""
     ok, why = supports_paged(cfg)
     if not ok:
         raise NotImplementedError(why)
@@ -107,7 +119,7 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
     segs = M.build_segments(cfg)
     windows = [w for _, w in segment_windows(cfg)]
 
-    def unit_step(p, x1, cache, lengths, active, page_tables, *,
+    def unit_step(p, layer, x1, cache, lengths, active, page_tables, *,
                   window: int, use_moe: bool):
         # mirrors model decode_unit / attention.decode_gqa op-for-op
         with jax.named_scope("model.attn"):
@@ -118,11 +130,13 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
             q = apply_rope(q, pos, cfg.rope_theta)
             k_new = apply_rope(k_new, pos, cfg.rope_theta)
             with jax.named_scope("decode.kv_write"):
-                cache = pa.write_kv(cache, k_new[:, 0], v_new[:, 0],
-                                    page_tables, lengths, active)
+                cache = pa.write_kv(cache, layer, k_new[:, 0],
+                                    v_new[:, 0], page_tables, lengths,
+                                    active)
             with jax.named_scope("decode.paged_attention"):
-                o = pa.paged_attention(q, cache, page_tables, lengths,
-                                       window=window, backend=backend)
+                o = pa.paged_attention(q, cache, layer, page_tables,
+                                       lengths, window=window,
+                                       backend=backend)
             a = o.reshape(x1.shape[0], 1, -1) @ p["attn"]["wo"]
         if cfg.parallel_residual and not use_moe:
             f, _ = M.apply_ffn_unit(p, x1, cfg, use_moe=use_moe)
@@ -141,14 +155,16 @@ def make_paged_decode_step(cfg: ModelConfig, *, backend: str = "ref",
                                         caches):
             use_moe = s.kind == "moe"
 
-            def scan_fn(x1, pc, _w=window, _m=use_moe):
-                p, c = pc
-                x1, c = unit_step(p, x1, c, lengths, active, page_tables,
-                                  window=_w, use_moe=_m)
-                return x1, c
+            def scan_fn(carry, pl_, _w=window, _m=use_moe):
+                x1, c = carry
+                p, layer = pl_
+                return unit_step(p, layer, x1, c, lengths, active,
+                                 page_tables, window=_w, use_moe=_m), None
 
             with jax.named_scope("decode.layers"):
-                x1, nc = jax.lax.scan(scan_fn, x1, (sp, cache))
+                (x1, nc), _ = jax.lax.scan(
+                    scan_fn, (x1, cache),
+                    (sp, jnp.arange(s.n, dtype=jnp.int32)))
             new_caches.append(nc)
         with jax.named_scope("model.head"):
             logits = M.logits_fn(params, cfg, x1)

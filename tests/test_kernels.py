@@ -312,12 +312,12 @@ def test_fused_ops_dispatch(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def _paged_fixture(seed, *, S, P, ps, KV, G, dh, fill_frac=0.8,
-                   lengths=None):
-    """Random page pool + table + lengths; scratch page 0 holds garbage to
-    prove the masking contract kills unallocated reads.  ``lengths``, when
-    given, fixes each slot's length; ``None`` in it is an inactive slot
-    (length 0, all-zero table row), and the pool then holds just the
-    pages the slots use."""
+                   lengths=None, units=1):
+    """Random lane-dense page pool ``(units, 1 + n_pages, ps, KV * dh)`` +
+    table + lengths; scratch page 0 holds garbage to prove the masking
+    contract kills unallocated reads.  ``lengths``, when given, fixes each
+    slot's length; ``None`` in it is an inactive slot (length 0, all-zero
+    table row), and the pool then holds just the pages the slots use."""
     from repro.serve.pages import PageManager
 
     rng = np.random.default_rng(seed)
@@ -335,10 +335,11 @@ def _paged_fixture(seed, *, S, P, ps, KV, G, dh, fill_frac=0.8,
         for pos in range(int(lengths[s]) + 1):
             pm.ensure(s, pos)
     H = KV * G
-    k = rng.normal(size=(1 + n_pages, ps, KV, dh)).astype(np.float32)
-    v = rng.normal(size=(1 + n_pages, ps, KV, dh)).astype(np.float32)
-    k[0] = 1e3          # scratch-page garbage must never leak into outputs
-    v[0] = 1e3
+    shape = (units, 1 + n_pages, ps, KV * dh)
+    k = rng.normal(size=shape).astype(np.float32)
+    v = rng.normal(size=shape).astype(np.float32)
+    k[:, 0] = 1e3       # scratch-page garbage must never leak into outputs
+    v[:, 0] = 1e3
     q = rng.normal(size=(S, 1, H, dh)).astype(np.float32)
     cache = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
     return (jnp.asarray(q), cache, jnp.asarray(pm.page_table),
@@ -363,9 +364,9 @@ def test_paged_attention_pallas_matches_ref(window, S, P, ps, KV, G, dh,
 
     q, cache, table, lengths = _paged_fixture(0, S=S, P=P, ps=ps, KV=KV,
                                               G=G, dh=dh, lengths=lengths)
-    ref_out = ap.ref_paged_attention(q, cache, table, lengths,
+    ref_out = ap.ref_paged_attention(q, cache, 0, table, lengths,
                                      window=window)
-    pal_out = ap.pallas_paged_attention(q, cache, table, lengths,
+    pal_out = ap.pallas_paged_attention(q, cache, 0, table, lengths,
                                         window=window)
     assert not np.isnan(np.asarray(pal_out)).any()
     np.testing.assert_allclose(np.asarray(pal_out), np.asarray(ref_out),
@@ -380,8 +381,8 @@ def test_paged_attention_pallas_property(seed, ps, g):
 
     q, cache, table, lengths = _paged_fixture(seed, S=2, P=3, ps=ps, KV=2,
                                               G=g, dh=8)
-    ref_out = ap.ref_paged_attention(q, cache, table, lengths)
-    pal_out = ap.pallas_paged_attention(q, cache, table, lengths)
+    ref_out = ap.ref_paged_attention(q, cache, 0, table, lengths)
+    pal_out = ap.pallas_paged_attention(q, cache, 0, table, lengths)
     np.testing.assert_allclose(np.asarray(pal_out), np.asarray(ref_out),
                                rtol=1e-3, atol=1e-5)
 
@@ -390,16 +391,57 @@ def test_paged_write_kv_routes_inactive_to_scratch():
     from repro.serve import attention_paged as ap
 
     ps, KV, dh = 4, 2, 8
-    cache = {"k": jnp.zeros((5, ps, KV, dh)), "v": jnp.zeros((5, ps, KV, dh))}
+    cache = {"k": jnp.zeros((1, 5, ps, KV * dh)),
+             "v": jnp.zeros((1, 5, ps, KV * dh))}
     table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lengths = jnp.asarray([5, 2], jnp.int32)       # row 0 -> page 2 slot 1
     k_new = jnp.ones((2, KV, dh))
-    out = ap.write_kv(cache, k_new, k_new, table,
+    out = ap.write_kv(cache, 0, k_new, k_new, table,
                       lengths, jnp.asarray([True, False]))
-    k = np.asarray(out["k"])
+    k = np.asarray(out["k"])[0]
     assert k[2, 1].all()                            # active row landed
     assert not k[3].any() and not k[4].any()        # inactive row did not
     assert k[0, 2].all()                            # ... it went to scratch
+
+
+@pytest.mark.parametrize("G", [1, 2])
+def test_paged_pool_layer_touches_its_unit_only(G):
+    """A 3-unit pool of distinct contents: the kernel at layer 1 reads
+    layer 1 alone (the other units' contents do not change its output, and
+    layer 0 reads otherwise), and ``write_kv`` at layer 1 changes only the
+    S rows it writes there."""
+    from repro.serve import attention_paged as ap
+
+    S, P, ps, KV, dh = 3, 4, 8, 2, 64
+    q, cache, table, lengths = _paged_fixture(1, S=S, P=P, ps=ps, KV=KV,
+                                              G=G, dh=dh, units=3)
+    out = ap.pallas_paged_attention(q, cache, 1, table, lengths)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(ap.ref_paged_attention(q, cache, 1, table, lengths)),
+        rtol=1e-3, atol=1e-5)
+    others = {n: c.at[jnp.asarray([0, 2])].set(1e3) for n, c in cache.items()}
+    np.testing.assert_array_equal(
+        np.asarray(ap.pallas_paged_attention(q, others, 1, table, lengths)),
+        np.asarray(out))
+    assert not np.allclose(
+        np.asarray(ap.pallas_paged_attention(q, cache, 0, table, lengths)),
+        np.asarray(out))
+
+    rng = np.random.default_rng(2)
+    k_new = jnp.asarray(rng.normal(size=(S, KV, dh)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(S, KV, dh)), jnp.float32)
+    active = jnp.ones((S,), bool)
+    new = ap.write_kv(cache, 1, k_new, v_new, table, lengths, active)
+    t, n = np.asarray(table), np.asarray(lengths)
+    phys, slot = t[np.arange(S), n // ps], n % ps
+    for name, rows in (("k", k_new), ("v", v_new)):
+        before, after = np.asarray(cache[name]), np.array(new[name])
+        np.testing.assert_array_equal(after[[0, 2]], before[[0, 2]])
+        np.testing.assert_array_equal(after[1, phys, slot],
+                                      np.asarray(rows).reshape(S, -1))
+        after[1, phys, slot] = before[1, phys, slot]
+        np.testing.assert_array_equal(after, before)
 
 
 def test_flash_attention_refuses_a_gradient():

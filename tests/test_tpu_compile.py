@@ -84,17 +84,18 @@ def _kernel_cases(sds):
     cases = {
         "paged_attention": (
             lambda q, k, v, t, n: pallas_paged_attention(
-                q, {"k": k, "v": v}, t, n),
-            sds((S, 1, H, dh)), sds((1 + S * pages, ps, H, dh)),
-            sds((1 + S * pages, ps, H, dh)), sds((S, pages), i32),
+                q, {"k": k, "v": v}, 1, t, n),
+            sds((S, 1, H, dh)), sds((2, 1 + S * pages, ps, H * dh)),
+            sds((2, 1 + S * pages, ps, H * dh)), sds((S, pages), i32),
             sds((S,), i32)),
         # serve-chat's shape: 32 slots of 96 pages of 8 over a 768-page
-        # pool, OPT-1.3B's 32 MHA heads
+        # pool of OPT-1.3B's 24 layers, 32 MHA heads
         "paged_attention_serve_chat": (
             lambda q, k, v, t, n: pallas_paged_attention(
-                q, {"k": k, "v": v}, t, n),
-            sds((32, 1, H, dh)), sds((1 + 768, ps, H, dh)),
-            sds((1 + 768, ps, H, dh)), sds((32, 96), i32), sds((32,), i32)),
+                q, {"k": k, "v": v}, 23, t, n),
+            sds((32, 1, H, dh)), sds((24, 1 + 768, ps, H * dh)),
+            sds((24, 1 + 768, ps, H * dh)), sds((32, 96), i32),
+            sds((32,), i32)),
         "flash_attention": (flash_attention_pallas,
                             *[sds((1, 2048, H, dh))] * 3),
         "quant4_pack": (quant4_pack_pallas, sds((2048 * 64,))),
@@ -260,3 +261,88 @@ def test_phi3_medium_round_on_two_clusters_fits_v5e_2x2(topo):
     # of the clusters' losses that the step reports
     inner = parse_collective_bytes(train.as_text(), cluster_size=2)
     assert inner["_cross_cluster_by_dtype"] == {"f32": 4}, inner
+
+
+def _hlo_ops(text):
+    """``name -> (opcode, dtype, dims, line)`` of every instruction of a
+    compiled module's text, fused computations included, and ``computation
+    name -> its ROOT's opcode``."""
+    import re
+    inst = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w-]+)\(")
+    ops, roots, comp = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = inst.match(line)
+        if not m:
+            continue
+        name, dt, dims, op = m.groups()
+        ops[name] = (op, dt, tuple(int(d) for d in dims.split(",") if d),
+                     line)
+        if line.lstrip().startswith("ROOT"):
+            roots[comp] = op
+    return ops, roots
+
+
+def test_serve_chat_decode_step_updates_its_pool_in_place(one_chip):
+    """The benchmark's serve-chat step (OPT-1.3B, all 24 layers, 32 slots
+    of 96 pages of 8 over a 768-page pool, the Pallas kernel, the pool
+    donated) for a described v5e.  The layer loop carries the lane-dense
+    pool and the kernel reads it as stored, so the step aliases exactly
+    the pool's bytes (2,419,064,832; pages-minor and copied per layer
+    before, 2,818,572,288 with 5.67 GB of temporaries), and nothing moves
+    a layer's worth of pool: no copy or slice of it, and the only
+    pool-sized fusions are the K and V scatters of each layer's new rows,
+    which update the carried pool in place."""
+    from repro.models import model as M
+    from repro.serve import engine as E
+
+    cfg = get_config("opt-1.3b")
+    S, pages, n_pages, ps = 32, 96, 768, 8
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = put(jax.eval_shape(
+        lambda: E.init_kv_pages(cfg, n_pages=n_pages, page_size=ps)))
+    vec = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(E.make_paged_decode_step(cfg, backend="pallas"),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, caches, vec(jnp.int32, S),
+                          vec(jnp.int32, S), vec(bool, S),
+                          vec(jnp.int32, S, pages)).compile()
+
+    ma = compiled.memory_analysis()
+    pool = E.kv_pool_bytes(cfg, n_pages=n_pages, page_size=ps)
+    assert pool == 2_419_064_832
+    assert ma.alias_size_in_bytes == pool
+    # the temporaries are the step's bfloat16 copies of the weights (2.42
+    # GB); none is pool-sized
+    assert ma.temp_size_in_bytes < 3.3e9, ma.temp_size_in_bytes
+
+    ops, roots = _hlo_ops(compiled.as_text())
+    layer_pool = pool // (2 * cfg.n_layers)     # one layer of the K pool
+    moves = {"copy", "copy-start", "copy-done", "dynamic-slice",
+             "dynamic-update-slice", "fusion"}
+    nbytes = lambda dt, dims: math.prod(dims) * max(
+        1, int("".join(c for c in dt if c.isdigit()) or 8) // 8)
+    # pool-derived arrays: those with an axis of the pool's 1 + n_pages
+    big = {n: o for n, o in ops.items() if o[0] in moves
+           and 1 + n_pages in o[2] and nbytes(*o[1:3]) >= layer_pool}
+    scatters = {n for n, o in big.items() if o[0] == "fusion"
+                and roots.get(o[3].split("calls=%")[1].split(",")[0])
+                == "scatter"}
+    assert len(scatters) == 2 and all(
+        "decode.kv_write" in big[n][3] for n in scatters), big.keys()
+    assert set(big) == scatters, \
+        {n: o[3][:160] for n, o in big.items() if n not in scatters}
+
+    kernels_ = [o[3] for o in ops.values() if o[0] == "custom-call"
+                and 'custom_call_target="tpu_custom_call"' in o[3]]
+    assert len(kernels_) == 1 and "paged_attention" in kernels_[0]
+    first = kernels_[0].split("custom-call(%")[1].split(",")[0]
+    assert ops[first][1:3] == ("s32", (S, pages)), ops[first][:3]
